@@ -14,6 +14,11 @@ The delta-push promotion loop end to end, across real processes:
    serve of step B (hot-swapped weights == cold-loaded weights);
 5. no ``repro-io-*`` /dev/shm segment (worker arenas, staging slots, or
    cache segments) may survive the fleet.
+
+This is a CPU smoke: this process and both servers run JAX at once, and
+an accelerator belongs to one process at a time, so every process is
+forced onto the CPU.  ``python chip_smoke.py`` is the one-process check
+of the same promotion path on a TPU.
 """
 from __future__ import annotations
 
@@ -25,6 +30,9 @@ import subprocess
 import sys
 import tempfile
 from pathlib import Path
+
+# before JAX loads here; the servers inherit it
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 SRC = Path(__file__).resolve().parent.parent / "src"
 if str(SRC) not in sys.path:
@@ -63,9 +71,11 @@ def main() -> int:
                              env=env),
         ]
 
-        # The promotion: resume training, committing step 20..40 into the
-        # store the fleet is polling.
-        train(ckpt_dir=str(tmp), total_steps=40, resume=True, **TRAIN)
+        # The promotion: resume training, committing step 20 into the
+        # store the fleet is polling.  One event only: a server that
+        # starts after several commits would otherwise promote straight
+        # to a step whose parity events re-saved every unit.
+        train(ckpt_dir=str(tmp), total_steps=20, resume=True, **TRAIN)
 
         outs = []
         for p in fleet:
@@ -73,16 +83,14 @@ def main() -> int:
             assert p.returncode == 0, f"server died rc={p.returncode}"
             outs.append(json.loads(raw))
 
-        # Each server promoted to whichever committed step its first
-        # successful poll saw (20/30/40 — timing-dependent, all valid).
-        # The invariant under test is step-agnostic: hot-swapped weights
-        # must generate bit-identically to a COLD restore of that step.
+        # The invariant under test: hot-swapped weights must generate
+        # bit-identically to a COLD restore of the step they promoted to.
         from repro.launch.serve import serve
         refs = {}
         for out in outs:
             step = out["served_step"]
             swap = out["swap"]
-            assert swap and swap["step_from"] == 10 and step > 10, out
+            assert swap and swap["step_from"] == 10 and step == 20, out
             # parity policy re-saves a subset of units per event: the
             # inherited entries keep their digests, so a digest-diffed
             # swap must skip at least one unit (the whole point).

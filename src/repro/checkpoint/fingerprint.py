@@ -43,6 +43,12 @@ PyTree = Any
 TABLE_VERSION = 1
 DIGEST_BYTES = 20  # same width as the canonical-payload digests
 
+#: per-event ``last_save_stats`` counters of the leaves fingerprinted and
+#: gathered on device, by the path that ran them: the Pallas kernels
+#: (``block_fp`` / ``block_gather``) or plain XLA ops
+KERNEL_LEAF_KEYS = ("fp_leaves_pallas", "fp_leaves_xla",
+                    "gather_leaves_pallas", "gather_leaves_xla")
+
 
 # ------------------------------------------------------------------- tables
 def pack_table(leaves: Sequence[LeafFP]) -> bytes:

@@ -57,6 +57,7 @@ import dataclasses
 import logging
 import math
 import time
+from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -166,6 +167,7 @@ class _Event:
     blocks_moved: int = 0
     blocks_total: int = 0
     overflows: int = 0
+    kernel_leaves: Counter = dataclasses.field(default_factory=Counter)
 
 
 def _byte_view(arr: np.ndarray) -> np.ndarray:
@@ -314,6 +316,10 @@ class OverlappedSaver:
         else:
             cur = bfp.fingerprint_tree(tree, block_bytes=bb,
                                        interpret=self.interpret)
+        path = bfp.kernel_path(self.interpret)
+        ev.kernel_leaves[f"fp_leaves_{path}"] += len(arrs)
+        if results is not None:
+            ev.kernel_leaves[f"gather_leaves_{path}"] += len(arrs)
         faults.crash_point("fingerprint")
 
         # The fingerprint tables are ~0.02% of the data: fetching them
@@ -555,6 +561,7 @@ class OverlappedSaver:
             step=ev.step, selected=ev.selected, d2h_bytes=ev.d2h_bytes,
             blocks_moved=ev.blocks_moved, blocks_total=ev.blocks_total,
             storage=storage, workers0=ev.workers0,
+            kernel_leaves=ev.kernel_leaves,
             timings={"snapshot_seconds": ev.begin_seconds,
                      "stage_seconds": ev.stage_seconds,
                      "writeback_seconds": ev.writeback_seconds,

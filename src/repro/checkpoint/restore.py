@@ -51,7 +51,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.chunk_store import ChunkRef, ChunkStore, ReadSession
-from repro.checkpoint.serial import ChunkCorruption
+from repro.checkpoint.serial import (
+    ChunkCorruption,
+    flatten_with_paths,
+    unflatten_from_paths,
+)
 from repro.checkpoint.sharded import (
     WantedFn,
     assemble_shards,
@@ -458,7 +462,25 @@ class _Placer:
             partial = len(g["parts"]) < g["total"]
             assembled = assemble_shards(g["parts"], partial=partial)
             g["parts"] = []
+            if partial:
+                assembled = self._fill_unread_leaves(unit, kind, assembled)
             self.add(unit, kind, assembled)
+
+    def _fill_unread_leaves(self, unit: str, kind: str,
+                            tree: PyTree) -> PyTree:
+        """Complete an owned-filtered assembly: a leaf none of whose shard
+        objects overlap the caller's slices was never read, and restores
+        as zeros like every other unread block."""
+        lead = 1 if self.registry.by_name[unit].index is not None else 0
+        likes = [jax.tree.map(
+            lambda s: np.zeros(tuple(s.shape)[lead:], s.dtype),
+            get_at(self.state_like, root))
+            for root in self._roots(unit, kind)]
+        full = likes[0] if kind == "weights" else dict(zip(OPT_KINDS, likes))
+        have = dict(flatten_with_paths(tree))
+        return unflatten_from_paths({
+            path: have.get(path, zero)
+            for path, zero in flatten_with_paths(full)})
 
     def add(self, unit: str, kind: str, tree: PyTree) -> None:
         u = self.registry.by_name[unit]

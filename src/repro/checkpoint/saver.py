@@ -312,6 +312,7 @@ class CheckpointManager:
         d2h_bytes = 0
         blocks_moved = 0
         blocks_total = 0
+        kernel_leaves: Counter = Counter()
         pending: Dict[Tuple[str, str], PendingResult] = {}
         new_fps: Dict[Tuple[str, str], Any] = {}
         for name in selected:
@@ -338,6 +339,7 @@ class CheckpointManager:
                 d2h_bytes += ustat["d2h_bytes"]
                 blocks_moved += ustat["blocks_moved"]
                 blocks_total += ustat["blocks_total"]
+                kernel_leaves.update(ustat["kernel_leaves"])
                 new_fps[(name, kind)] = cur
                 if isinstance(res, PendingResult):
                     pending[(name, kind)] = res
@@ -363,7 +365,7 @@ class CheckpointManager:
         self.last_save_stats = self._event_stats(
             step=step, selected=selected, d2h_bytes=d2h_bytes,
             blocks_moved=blocks_moved, blocks_total=blocks_total,
-            storage=storage, workers0=workers0,
+            storage=storage, workers0=workers0, kernel_leaves=kernel_leaves,
             timings={"snapshot_seconds": t_snapshot,
                      "stage_seconds": 0.0,
                      "writeback_seconds": t_writeback,
@@ -430,7 +432,8 @@ class CheckpointManager:
 
     def _event_stats(self, *, step: int, selected, d2h_bytes: int,
                      blocks_moved: int, blocks_total: int, storage,
-                     workers0, timings: Dict[str, float]) -> Dict[str, Any]:
+                     workers0, kernel_leaves: Counter,
+                     timings: Dict[str, float]) -> Dict[str, Any]:
         """Assemble one event's ``last_save_stats`` dict.
 
         ``timings`` carries the four-way split (docs/perf.md):
@@ -439,6 +442,8 @@ class CheckpointManager:
         buffers), ``writeback_seconds`` (encode+write drain), and
         ``stall_seconds`` — the time the *caller's step loop* actually
         blocked, the number the zero-stall pipeline exists to shrink.
+        ``kernel_leaves`` counts the leaves each device path fingerprinted
+        and gathered (``fingerprint.KERNEL_LEAF_KEYS``).
         """
         pool = self.transfer_pool
         io = dict(self.store.stats)
@@ -469,6 +474,7 @@ class CheckpointManager:
             # which worker backend ran the byte work (hash/codec/write)
             "io_backend": (pool.dispatch.backend if pool is not None
                            else "thread"),
+            **{k: kernel_leaves.get(k, 0) for k in fputil.KERNEL_LEAF_KEYS},
         }
         if workers0 is not None:
             # Process backend: this event's share of the subprocess
@@ -501,7 +507,9 @@ class CheckpointManager:
         faults.crash_point("fingerprint")
         nb_total = sum(l.n_blocks for l in cur)
         logical = sum(l.nbytes for l in cur)
-        stats = {"d2h_bytes": 0, "blocks_moved": 0, "blocks_total": nb_total}
+        stats = {"d2h_bytes": 0, "blocks_moved": 0, "blocks_total": nb_total,
+                 "kernel_leaves": Counter(
+                     {f"fp_leaves_{bfp.kernel_path()}": len(cur)})}
 
         # Reference vector for the content behind the previous manifest
         # entry: device-resident from the last commit, or (after a process
@@ -558,6 +566,7 @@ class CheckpointManager:
                     data = np.ascontiguousarray(g).tobytes()
                     stats["d2h_bytes"] += len(data)
                     stats["blocks_moved"] += len(idx)
+                    stats["kernel_leaves"]["gather_leaves_xla"] += 1
                 leaves.append(fputil.LeafPayload(
                     path=path, shape=leaf.shape, dtype=leaf.dtype,
                     nbytes=leaf.nbytes, block_bytes=bb, idx=idx, data=data))

@@ -49,6 +49,7 @@ import dataclasses
 import logging
 import shutil
 import time
+from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -60,6 +61,7 @@ from repro.checkpoint import faults
 from repro.checkpoint.async_io import PendingResult
 from repro.checkpoint.backends.localfs import atomic_write
 from repro.checkpoint.chunk_store import ChunkRef
+from repro.checkpoint.fingerprint import KERNEL_LEAF_KEYS
 from repro.checkpoint.serial import (
     flatten_with_paths,
     shard_leaf_key,
@@ -450,6 +452,7 @@ class ShardedSaver:
         d2h_bytes = 0
         blocks_moved = 0
         blocks_total = 0
+        kernel_leaves: Counter = Counter()
         pending: Dict[Tuple[str, str], PendingResult] = {}
         refs: Dict[Tuple[str, str], ChunkRef] = {}
         specs: Dict[Tuple[str, str], Dict[str, Any]] = {}
@@ -482,6 +485,7 @@ class ShardedSaver:
                 d2h_bytes += ustat["d2h_bytes"]
                 blocks_moved += ustat["blocks_moved"]
                 blocks_total += ustat["blocks_total"]
+                kernel_leaves.update(ustat["kernel_leaves"])
                 new_fps[(ukey, kind)] = cur
                 if isinstance(res, PendingResult):
                     pending[(name, kind)] = res
@@ -531,6 +535,7 @@ class ShardedSaver:
             "d2h_bytes": d2h_bytes,
             "blocks_moved": blocks_moved,
             "blocks_total": blocks_total,
+            "kernel_leaves": kernel_leaves,
             "seconds": time.time() - t0,
         }
         return ParticipantResult(self.participant_id, step, path, refs,
@@ -787,6 +792,8 @@ class ShardedCheckpointer:
         d2h = sum(r.stats["d2h_bytes"] for r in results)
         moved = sum(r.stats["blocks_moved"] for r in results)
         total = sum(r.stats["blocks_total"] for r in results)
+        kernel_leaves = sum((r.stats["kernel_leaves"] for r in results),
+                            Counter())
         self.mgr.last_save_stats = {
             "step": step,
             "selected_units": len(manifest.saved_units),
@@ -807,6 +814,7 @@ class ShardedCheckpointer:
             "backend": manifest.meta["storage"]["backend"],
             "durable_on": manifest.meta["storage"]["durable_on"],
             "spill_pending": manifest.meta["storage"]["pending_spill"],
+            **{k: kernel_leaves.get(k, 0) for k in KERNEL_LEAF_KEYS},
         }
         return manifest
 

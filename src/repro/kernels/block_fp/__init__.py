@@ -2,6 +2,7 @@ from repro.kernels.block_fp.ops import (  # noqa: F401
     block_fingerprint,
     fingerprint_tree,
     gather_blocks,
+    kernel_path,
     leaves_match,
     tree_to_host,
 )

@@ -3,12 +3,12 @@ compare fingerprint vectors, and gather only dirty blocks for the
 device->host transfer.
 
 ``interpret=None`` (the default at every production call site) auto-selects
-the implementation: the Pallas kernel on TPU, an op-identical plain-jnp
-reduction elsewhere (same bitcasts, same wrap-around uint32 arithmetic, so
-the checksums are bit-identical — interpret-mode Pallas would only add
-compile latency on CPU).  Pass ``interpret=True`` to force the Pallas
-kernel through the interpreter (how the property tests exercise the kernel
-body off-TPU).
+the implementation: the Pallas kernel on TPU, the kernel's own math as one
+plain-jnp reduction elsewhere (same integer view, same wrap-around int32
+arithmetic, so the checksums are bit-identical — interpret-mode Pallas
+would only add compile latency on CPU).  Pass ``interpret=True`` to force
+the Pallas kernel through the interpreter (how the property tests exercise
+the kernel body off-TPU).
 """
 from __future__ import annotations
 
@@ -19,7 +19,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.block_fp.kernel import _words_view, fingerprint_blocks
+from repro.kernels.block_fp.kernel import (
+    block_slabs,
+    checksums,
+    fingerprint_slabs,
+    int_view,
+)
 from repro.kernels.block_fp.ref import DEFAULT_BLOCK_BYTES, LeafFP
 
 _ROWS = 8  # blocks per grid tile: 8 x 64KiB = 512 KiB of VMEM per input tile
@@ -31,47 +36,72 @@ def _impl(interpret: Optional[bool]) -> str:
     return "pallas-interpret" if interpret else "pallas"
 
 
+def kernel_path(interpret: Optional[bool] = None) -> str:
+    """Which path fingerprints a leaf: ``"pallas"`` (the kernel, compiled
+    or interpreted) or ``"xla"`` (the plain-jnp reduction)."""
+    return "xla" if _impl(interpret) == "jnp" else "pallas"
+
+
 def _block_elems(dtype, block_bytes: int) -> int:
     itemsize = jnp.dtype(dtype).itemsize
     assert block_bytes % itemsize == 0, (block_bytes, itemsize)
     return block_bytes // itemsize
 
 
-def _as_blocks(x: jax.Array, epb: int, pad_rows: bool) -> jax.Array:
-    """Flatten and zero-pad to a (n_blocks, epb) view (+ tile padding)."""
-    flat = x.reshape(-1)
+def _padded(flat: jax.Array, epb: int, pad_rows: bool) -> jax.Array:
+    """Zero-pad a flat array to whole blocks (+ whole tiles)."""
     nb = max(1, -(-flat.size // epb))
     if pad_rows:
         nb = -(-nb // _ROWS) * _ROWS
     pad = nb * epb - flat.size
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(nb, epb)
+    return jnp.pad(flat, (0, pad)) if pad else flat
 
 
-def _fingerprint_jnp(blocks: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def _as_blocks(x: jax.Array, epb: int, pad_rows: bool) -> jax.Array:
+    """Flatten and zero-pad to a (n_blocks, epb) view (+ tile padding)."""
+    return _padded(x.reshape(-1), epb, pad_rows).reshape(-1, epb)
+
+
+def _as_slabs(x: jax.Array, block_bytes: int, pad_rows: bool) -> jax.Array:
+    """Flatten, take the integer view and zero-pad to the kernels'
+    (n_blocks, sub, lanes) slab layout (+ tile padding)."""
+    v = int_view(x.reshape(-1))
+    epb = _block_elems(v.dtype, block_bytes)
+    return block_slabs(_padded(v, epb, pad_rows), epb)
+
+
+def _fingerprint_jnp(x: jax.Array, block_bytes: int
+                     ) -> Tuple[jax.Array, jax.Array]:
     """The kernel's math as one vectorized jnp reduction (non-TPU path)."""
-    words = _words_view(blocks)
-    weights = jax.lax.broadcasted_iota(
-        jnp.uint32, words.shape, dimension=1) + jnp.uint32(1)
-    # dtype pinned so the sums wrap mod 2^32 even under jax_enable_x64
-    fp1 = jnp.sum(words, axis=1, dtype=jnp.uint32)
-    fp2 = jnp.sum(words * weights, axis=1, dtype=jnp.uint32)
-    vals = blocks.astype(jnp.float32)
-    return jnp.stack([fp1, fp2], axis=1), jnp.sum(vals * vals, axis=1)
+    fp = jnp.concatenate(
+        checksums(_as_slabs(x, block_bytes, pad_rows=False)), axis=1)
+    vals = _as_blocks(x, _block_elems(x.dtype, block_bytes),
+                      pad_rows=False).astype(jnp.float32)
+    return (jax.lax.bitcast_convert_type(fp, jnp.uint32),
+            jnp.sum(vals * vals, axis=1))
+
+
+def _fingerprint_pallas(x: jax.Array, slabs: jax.Array, block_bytes: int,
+                        impl: str) -> Tuple[jax.Array, jax.Array]:
+    """The kernel over ``x``'s tile-padded slabs; dtypes the kernel cannot
+    decode get their advisory sumsq from the original values."""
+    fp, ss = fingerprint_slabs(slabs, x.dtype, rows_per_tile=_ROWS,
+                               interpret=impl == "pallas-interpret")
+    if ss is None:
+        vals = _as_blocks(x, _block_elems(x.dtype, block_bytes),
+                          pad_rows=True).astype(jnp.float32)
+        ss = jnp.sum(vals * vals, axis=1)
+    return fp, ss
 
 
 def _fingerprint_one(x, *, block_bytes, n_blocks, impl):
     if x.dtype == jnp.bool_:
         x = x.astype(jnp.uint8)
-    epb = _block_elems(x.dtype, block_bytes)
     if impl == "jnp":
-        fp, ss = _fingerprint_jnp(_as_blocks(x, epb, pad_rows=False))
+        fp, ss = _fingerprint_jnp(x, block_bytes)
     else:
-        blocks = _as_blocks(x, epb, pad_rows=True)
-        fp, ss2 = fingerprint_blocks(blocks, rows_per_tile=_ROWS,
-                                     interpret=impl == "pallas-interpret")
-        ss = ss2[:, 0]
+        fp, ss = _fingerprint_pallas(
+            x, _as_slabs(x, block_bytes, pad_rows=True), block_bytes, impl)
     return fp[:n_blocks], ss[:n_blocks]
 
 

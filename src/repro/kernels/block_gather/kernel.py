@@ -1,103 +1,47 @@
-"""Fused fingerprint-compare + dirty-block compaction kernel (Pallas).
+"""Dirty-block gather kernel (Pallas): copy the listed blocks of a leaf
+into a dense ``(capacity, ...)`` buffer, so the device->host copy ships
+exactly the changed bytes plus a tiny index vector instead of full arrays.
 
-One pass over a unit's blocks computes the per-block fingerprint pair
-(the exact ``block_fp`` math), compares it against a reference table ON
-DEVICE, and compacts the dirty blocks into a dense ``(capacity, elems)``
-buffer — so the device->host copy ships exactly the changed bytes plus a
-tiny index vector instead of full arrays (ROADMAP item-3 stretch: shrink
-what the host must push at all).
+The fused gather of ``ops.py`` runs the ``block_fp`` fingerprint kernel
+over every block, compacts the dirty indices with a few tiny XLA ops on
+the ``(n_blocks,)`` flag vector, and then runs this kernel over only the
+``capacity`` listed blocks — all inside one jitted dispatch.  The index
+vector is scalar-prefetched into SMEM and drives the input ``BlockSpec``'s
+``index_map``, so the Pallas pipeline DMAs one listed block per grid step
+through VMEM: no state is carried across grid steps and no output buffer
+stays resident, so the capacity is bounded by HBM, not by VMEM.
 
-Grid: sequential tiles of ``rows`` blocks.  The per-tile fingerprint and
-sumsq outputs stream like ``block_fp``; the compacted outputs (index
-vector, dense block buffer, running count) are *revisited* blocks — their
-index_map pins them to block (0, 0) so they stay resident in VMEM across
-the whole grid and act as cross-tile carry state.  Each tile compacts its
-rows with a static loop of ``@pl.when``-guarded dynamic (``pl.ds``)
-stores against the carried count.
-
-Overflow contract: the count keeps counting past ``capacity`` (only the
-stores are capacity-guarded), so an undersized — mispredicted — capacity
-is *detectable* by the caller: the first ``capacity`` dirty blocks are
-still valid and in ascending order, and the caller re-runs with a bigger
-buffer.  Misprediction costs bandwidth, never correctness.
+Blocks are the ``block_fp`` slabs — ``(n_blocks, sub, lanes)`` integer
+views — so a block is one leading index and its tile is the array's own
+last two dimensions, which the TPU's tiling rule always accepts.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.block_fp.kernel import _words_view
-
-
-def _gather_kernel(x_ref, ref_ref, fp_ref, ss_ref, idx_ref, out_ref,
-                   cnt_ref, *, rows: int, capacity: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-        cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
-
-    x = x_ref[...]                                        # (rows, epb)
-    words = _words_view(x)                                # (rows, wpb) u32
-    weights = jax.lax.broadcasted_iota(
-        jnp.uint32, words.shape, dimension=1) + jnp.uint32(1)
-    # dtype pinned: wrap mod 2^32 even under jax_enable_x64 (see block_fp)
-    fp1 = jnp.sum(words, axis=1, dtype=jnp.uint32)
-    fp2 = jnp.sum(words * weights, axis=1, dtype=jnp.uint32)
-    fp = jnp.stack([fp1, fp2], axis=1)
-    fp_ref[...] = fp
-    vals = x.astype(jnp.float32)
-    ss_ref[...] = jnp.sum(vals * vals, axis=1, keepdims=True)
-
-    dirty = jnp.any(fp != ref_ref[...], axis=1)           # (rows,) bool
-    for r in range(rows):
-        pos = cnt_ref[0, 0]
-        is_dirty = dirty[r]
-
-        @pl.when(jnp.logical_and(is_dirty, pos < capacity))
-        def _store(r=r, pos=pos):
-            idx_ref[:, pl.ds(pos, 1)] = jnp.full(
-                (1, 1), i * rows + r, jnp.int32)
-            out_ref[pl.ds(pos, 1), :] = x[r:r + 1, :]
-
-        @pl.when(is_dirty)
-        def _bump(pos=pos):
-            cnt_ref[0, 0] = pos + jnp.int32(1)
+from jax.experimental.pallas import tpu as pltpu
 
 
-def gather_compact_blocks(x: jax.Array, ref_fp: jax.Array, *,
-                          capacity: int, rows_per_tile: int = 8,
-                          interpret: bool = False):
-    """x: (n_blocks, elems_per_block), ref_fp: (n_blocks, 2) uint32 ->
-    (fp (n_blocks, 2) uint32, sumsq (n_blocks, 1) f32,
-     idx (1, capacity) int32 (-1 fill), out (capacity, epb) x.dtype
-     (zero fill), count (1, 1) int32 counting ALL dirty blocks)."""
-    nb, epb = x.shape
-    assert ref_fp.shape == (nb, 2), (ref_fp.shape, nb)
-    assert capacity >= 1, capacity
-    rows = min(rows_per_tile, nb)
-    assert nb % rows == 0, (nb, rows)
-    grid = (nb // rows,)
-    kern = functools.partial(_gather_kernel, rows=rows, capacity=capacity)
+def _copy_kernel(idx_ref, slab_ref, out_ref):
+    del idx_ref  # consumed by the index_map
+    out_ref[...] = slab_ref[...]
+
+
+def gather_slabs(slabs: jax.Array, idx: jax.Array, *,
+                 interpret: bool = False) -> jax.Array:
+    """slabs: (n_blocks, sub, lanes), idx: (capacity,) int32 in
+    [0, n_blocks) -> (capacity, sub, lanes) with row c = slabs[idx[c]]."""
+    _, sub, lanes = slabs.shape
+    block = (None, sub, lanes)
     return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, epb), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, 2), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((rows, 2), lambda i: (i, 0)),
-                   pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((1, capacity), lambda i: (0, 0)),
-                   pl.BlockSpec((capacity, epb), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, 2), jnp.uint32),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((capacity, epb), x.dtype),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+        _copy_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(idx.shape[0],),
+            in_specs=[pl.BlockSpec(block, lambda c, ix: (ix[c], 0, 0))],
+            out_specs=pl.BlockSpec(block, lambda c, ix: (c, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], sub, lanes),
+                                       slabs.dtype),
         interpret=interpret,
-    )(x, ref_fp)
+        name="block_gather",
+    )(idx, slabs)

@@ -2,9 +2,11 @@
 capacity rounding, and the optional on-device int8 composition.
 
 ``interpret=None`` auto-selects exactly like ``block_fp.ops``: the Pallas
-kernel on TPU, an op-identical plain-jnp path elsewhere (same bitcasts,
-same wrap-around uint32 sums, ``jnp.nonzero(size=capacity)`` for the
-ascending compaction) so results are bit-identical.  Pass
+kernels on TPU (``block_fp`` fingerprints, then the ``block_gather`` copy
+of the listed blocks), an op-identical plain-jnp path elsewhere (same
+word view, same wrap-around sums, ``jnp.take`` for the copy); both
+compact with ``jnp.nonzero(size=capacity)``, so results are
+bit-identical.  Pass
 ``interpret=True`` to force the Pallas kernel through the interpreter
 (how the property tests exercise the kernel body off-TPU).
 
@@ -13,8 +15,8 @@ DeltaTracker drift signals), :func:`round_capacity` rounds it up to a
 power of two so recompilation is bounded at O(log n_blocks) variants per
 leaf structure, and the returned ``count`` is authoritative — ``count >
 capacity`` means the prediction was short and the caller re-gathers with
-a larger buffer.  On TPU a capacity whose dense buffer would not fit the
-VMEM carry budget falls back to the jnp path (same bits, streamed HBM).
+a larger buffer.  The dense buffer lives in HBM on every path, so any
+capacity up to ``n_blocks`` runs on the Pallas kernels.
 """
 from __future__ import annotations
 
@@ -25,20 +27,18 @@ from typing import Any, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.block_fp.kernel import from_int_view
 from repro.kernels.block_fp.ops import (
-    _ROWS,
     _as_blocks,
+    _as_slabs,
     _block_elems,
     _device_groups,
     _fingerprint_jnp,
+    _fingerprint_pallas,
     _impl,
 )
 from repro.kernels.block_fp.ref import DEFAULT_BLOCK_BYTES
-from repro.kernels.block_gather.kernel import gather_compact_blocks
-
-# The dense (capacity, epb) out buffer is VMEM-resident carry state in the
-# Pallas path; past this budget the jnp fallback streams through HBM.
-_VMEM_OUT_BUDGET = 8 * 2 ** 20
+from repro.kernels.block_gather.kernel import gather_slabs
 
 QUANT_BLOCK = 256  # quantize codec's elements per scale
 
@@ -87,30 +87,28 @@ def _quantize_jnp(x: jax.Array, block: int):
 def _gather_one(x, ref, *, block_bytes, n_blocks, capacity, impl, quant):
     if x.dtype == jnp.bool_:
         x = x.astype(jnp.uint8)
-    epb = _block_elems(x.dtype, block_bytes)
     ref = jnp.asarray(ref, jnp.uint32)
     if impl == "jnp":
-        blocks = _as_blocks(x, epb, pad_rows=False)
-        fp, ss = _fingerprint_jnp(blocks)
-        dirty = jnp.any(fp != ref, axis=1)
-        count = jnp.sum(dirty, dtype=jnp.int32)
-        (idx,) = jnp.nonzero(dirty, size=capacity, fill_value=-1)
-        idx = idx.astype(jnp.int32)
-        valid = idx >= 0
-        taken = jnp.take(blocks, jnp.where(valid, idx, 0), axis=0)
-        out = jnp.where(valid[:, None], taken, jnp.zeros((), blocks.dtype))
+        fp, ss = _fingerprint_jnp(x, block_bytes)
     else:
-        blocks = _as_blocks(x, epb, pad_rows=True)
-        pad = blocks.shape[0] - n_blocks
-        if pad:
-            # zero-padded tile rows fingerprint to (0, 0); pad the ref
-            # table to match so padding can never read as dirty
-            ref = jnp.concatenate([ref, jnp.zeros((pad, 2), jnp.uint32)])
-        fp, ss2, idx2, out, cnt = gather_compact_blocks(
-            blocks, ref, capacity=capacity, rows_per_tile=_ROWS,
-            interpret=impl == "pallas-interpret")
-        fp, ss = fp[:n_blocks], ss2[:n_blocks, 0]
-        idx, count = idx2[0], cnt[0, 0]
+        slabs = _as_slabs(x, block_bytes, pad_rows=True)
+        fp, ss = _fingerprint_pallas(x, slabs, block_bytes, impl)
+        fp, ss = fp[:n_blocks], ss[:n_blocks]
+    dirty = jnp.any(fp != ref, axis=1)
+    count = jnp.sum(dirty, dtype=jnp.int32)
+    (idx,) = jnp.nonzero(dirty, size=capacity, fill_value=-1)
+    idx = idx.astype(jnp.int32)
+    valid = idx >= 0
+    safe = jnp.where(valid, idx, 0)
+    if impl == "jnp":
+        blocks = _as_blocks(x, _block_elems(x.dtype, block_bytes),
+                            pad_rows=False)
+        taken = jnp.take(blocks, safe, axis=0)
+    else:
+        taken = gather_slabs(slabs, safe,
+                             interpret=impl == "pallas-interpret")
+        taken = from_int_view(taken.reshape(capacity, -1), x.dtype)
+    out = jnp.where(valid[:, None], taken, jnp.zeros((), x.dtype))
     if not quant:
         return fp, ss, idx, out, count, None, None
     q, scales = _quantize_jnp(out, QUANT_BLOCK)
@@ -131,13 +129,6 @@ def _gather_many(xs, refs, *, block_bytes, n_blocks, capacities, impl,
         for x, r, nb, c in zip(xs, refs, n_blocks, capacities))
 
 
-def _leaf_capacity(cap, nb, dtype, block_bytes, impl):
-    cap = round_capacity(cap, nb)
-    if impl == "pallas" and cap * block_bytes > _VMEM_OUT_BUDGET:
-        return cap, "jnp"
-    return cap, impl
-
-
 def gather_dirty(x: jax.Array, ref_fp, *, capacity: int,
                  block_bytes: int = DEFAULT_BLOCK_BYTES,
                  interpret: Optional[bool] = None,
@@ -148,11 +139,10 @@ def gather_dirty(x: jax.Array, ref_fp, *, capacity: int,
     epb = _block_elems(
         jnp.uint8 if x.dtype == jnp.bool_ else x.dtype, block_bytes)
     nb = max(1, -(-x.size // epb))
-    impl = _impl(interpret)
-    cap, impl = _leaf_capacity(capacity, nb, x.dtype, block_bytes, impl)
     (res,) = _gather_many(
         (x,), (jnp.asarray(ref_fp, jnp.uint32),), block_bytes=block_bytes,
-        n_blocks=(nb,), capacities=(cap,), impl=impl, quant=quantize_int8)
+        n_blocks=(nb,), capacities=(round_capacity(capacity, nb),),
+        impl=_impl(interpret), quant=quantize_int8)
     return GatherResult(*res)
 
 
@@ -171,15 +161,7 @@ def gather_tree_dirty(arrs: Sequence[jax.Array], ref_fps: Sequence[Any],
         epb = _block_elems(
             jnp.uint8 if a.dtype == jnp.bool_ else a.dtype, block_bytes)
         n_blocks.append(max(1, -(-a.size // epb)))
-    impl = _impl(interpret)
-    caps, impls = [], []
-    for a, nb, c in zip(arrs, n_blocks, capacities):
-        cap, im = _leaf_capacity(c, nb, a.dtype, block_bytes, impl)
-        caps.append(cap)
-        impls.append(im)
-    # one leaf over the VMEM budget demotes its whole dispatch group: the
-    # impl is static per jit call and the bits are identical either way
-    unit_impl = "jnp" if "jnp" in impls else impl
+    caps = [round_capacity(c, nb) for c, nb in zip(capacities, n_blocks)]
     out: List[Optional[GatherResult]] = [None] * len(arrs)
     for idxs in _device_groups(arrs):
         res = _gather_many(
@@ -188,7 +170,7 @@ def gather_tree_dirty(arrs: Sequence[jax.Array], ref_fps: Sequence[Any],
             block_bytes=block_bytes,
             n_blocks=tuple(n_blocks[i] for i in idxs),
             capacities=tuple(caps[i] for i in idxs),
-            impl=unit_impl, quant=quantize_int8)
+            impl=_impl(interpret), quant=quantize_int8)
         for i, r in zip(idxs, res):
             out[i] = GatherResult(*r)
     return out  # type: ignore[return-value]

@@ -35,7 +35,8 @@ def restore_on_mesh(ckpt_root: str | Path, model: BaseLM, mesh: Mesh,
                     units: Optional[Sequence[str]] = None,
                     pipelined: bool = True,
                     store_backend: str = "local",
-                    participant: Optional[Tuple[int, int]] = None
+                    participant: Optional[Tuple[int, int]] = None,
+                    stats: Optional[Dict[str, Any]] = None
                     ) -> Dict[str, PyTree]:
     """Restore a checkpoint sharded onto ``mesh``; thin wrapper over
     ``CheckpointManager.restore`` (``parts``/``units``/``pipelined``
@@ -52,7 +53,10 @@ def restore_on_mesh(ckpt_root: str | Path, model: BaseLM, mesh: Mesh,
     restore-on-PxQ resharding path that reads strictly fewer bytes than
     a full-array restore whenever the shardings overlap partially.  The
     returned state is only guaranteed correct on the participant's owned
-    slices (elsewhere zeros for sharded units)."""
+    slices (elsewhere zeros for sharded units).
+
+    ``stats``, when given, receives the restore's ``last_restore_stats``
+    (``bytes_read``, ``shards_skipped``, ...)."""
     registry = LayerRegistry(model)
     mgr = CheckpointManager(Path(ckpt_root), registry,
                             make_policy("full", model.layer_units()),
@@ -67,9 +71,12 @@ def restore_on_mesh(ckpt_root: str | Path, model: BaseLM, mesh: Mesh,
             pid, nparts = participant
             owned = participant_wanted(registry, pid, nparts,
                                        shardings=shardings)
-        return mgr.restore(like, step=step, shardings=shardings,
-                           parts=parts, units=units, pipelined=pipelined,
-                           owned=owned)
+        state = mgr.restore(like, step=step, shardings=shardings,
+                            parts=parts, units=units, pipelined=pipelined,
+                            owned=owned)
+        if stats is not None:
+            stats.update(mgr.last_restore_stats)
+        return state
     finally:
         mgr.close()
 

@@ -11,12 +11,8 @@ from jax.sharding import Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    # jax < 0.5 has neither sharding.AxisType nor the axis_types kwarg;
-    # Auto is the default there, so plain make_mesh is equivalent.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

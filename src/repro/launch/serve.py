@@ -3,7 +3,7 @@ decode against the KV/state cache — with delta-push weight promotion and
 variant serving from one store (docs/serving.md).
 
     python -m repro.launch.serve --arch llama3.2-3b --batch 4 \
-        --prompt-len 64 --new-tokens 32 [--from-ckpt /tmp/run1]
+        --prompt-len 64 --new-tokens 32 [--from-ckpt /tmp/run1] [--full]
 
 Weights can come from any LLMTailor checkpoint root — including a merged
 Frankenstein — because the bf16 weight chunks are servable without the
@@ -44,6 +44,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.core import LayerRegistry, make_policy
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 
 
@@ -205,9 +206,13 @@ def serve(*, arch: str, reduced: bool = True, batch: int = 4,
     }
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-3b")
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="use the reduced config (CPU-sized)")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the published full-size config")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=32)
@@ -259,8 +264,10 @@ def main() -> None:
     ap.add_argument("--io-workers", type=int,
                     help="process backend: subprocess IO worker count")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-    print(json.dumps(serve(arch=args.arch, batch=args.batch,
+    args = ap.parse_args(argv)
+    use_compile_cache()
+    print(json.dumps(serve(arch=args.arch, reduced=args.smoke,
+                           batch=args.batch,
                            prompt_len=args.prompt_len,
                            new_tokens=args.new_tokens,
                            from_ckpt=args.from_ckpt,

@@ -42,8 +42,11 @@ Lifecycle per attempt:
      (restart + restore + re-JIT; the optional pre-launch restore probe
      is counted in here too).
 
-4. Optionally probe restorability first (:func:`elastic.probe_restore`
-   on a single-host mesh), then relaunch.  Stop after ``max_restarts``
+4. Optionally scrub the store and probe restorability first
+   (:func:`elastic.probe_restore` on a single-host mesh), then relaunch.
+   Both run in a short child that exits before the relaunch: the
+   supervisor itself never imports JAX, so on an accelerator host every
+   trainer attempt can take the chip.  Stop after ``max_restarts``
    unscheduled deaths (injections don't count against it).
 
 ``run()`` returns the goodput report; the CLI (and
@@ -216,6 +219,24 @@ class Supervisor:
         argv += self.extra_args
         return argv
 
+    def _in_child(self, module: str, func: str, **kwargs) -> Dict[str, Any]:
+        """``module.func(**kwargs)`` in a short child process that exits
+        before the next attempt starts; returns its JSON result.  Scrub
+        and probe import JAX, and this process must stay off the
+        accelerator: a parent holding the chip would keep every relaunched
+        trainer from it."""
+        code = ("import json, sys\n"
+                f"from {module} import {func} as fn\n"
+                "out = fn(**json.loads(sys.argv[1]))\n"
+                "print(json.dumps(out, default=str))\n")
+        res = subprocess.run([sys.executable, "-c", code, json.dumps(kwargs)],
+                             env=self._child_env(), capture_output=True,
+                             text=True, check=False)
+        if res.returncode != 0:
+            raise RuntimeError(f"{module}.{func} failed in its child process "
+                               f"(exit {res.returncode}):\n{res.stderr}")
+        return json.loads(res.stdout.strip().splitlines()[-1])
+
     def _probe(self) -> Optional[Dict[str, Any]]:
         """Pre-relaunch restorability check (counted into MTTR)."""
         if not self.verify_restore:
@@ -224,26 +245,26 @@ class Supervisor:
             # Death before the first commit: nothing to probe, and the
             # relaunch (without --resume) starts from scratch anyway.
             return None
-        from repro.launch.elastic import probe_restore
-        return probe_restore(self.ckpt_dir, self.arch,
-                             store_backend=self.store_backend)
+        return self._in_child("repro.launch.elastic", "probe_restore",
+                              ckpt_root=str(self.ckpt_dir), arch=self.arch,
+                              store_backend=self.store_backend)
 
     def _scrub(self) -> Optional[Dict[str, Any]]:
         """Pre-relaunch integrity scrub (fsck): a crash is exactly when
         bit-rot or a torn tier copy surfaces, so repair/quarantine BEFORE
-        the next attempt plans its restore.  The scrub runs in the
-        supervisor process against the tiers that survive the dead child
+        the next attempt plans its restore.  The scrub runs in a child
+        between attempts against the tiers that survive the dead trainer
         ("local" disk view for RAM-hot backends — a child's hot tier died
         with it)."""
         if not self.scrub_on_restart:
             return None
         if _latest_committed(self.ckpt_dir) is None:
             return None
-        from repro.checkpoint.scrub import scrub_root
         backend = (self.store_backend
                    if self.store_backend in ("remote", "remote3")
                    else "local")
-        rep = scrub_root(self.ckpt_dir, backend=backend)
+        rep = self._in_child("repro.checkpoint.scrub", "scrub_root",
+                             root=str(self.ckpt_dir), backend=backend)
         return {"checked_objects": rep["checked_objects"],
                 "repaired": len(rep["repaired"]),
                 "unrecoverable": len(rep["unrecoverable"]),

@@ -47,6 +47,7 @@ from repro.checkpoint.saver import CheckpointManager
 from repro.checkpoint.sharded import ShardedCheckpointer
 from repro.data.synthetic import SyntheticTokens
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 
 log = logging.getLogger("repro.train")
@@ -226,6 +227,9 @@ def train(
             tracker.reset(state["params"])
 
     losses = []
+    # host wall time per step, dispatch to loss on host (the first one
+    # includes the compile; sync saves fall outside it)
+    step_seconds = []
     t0 = time.time()
     save_seconds = 0.0
     d2h_bytes = 0
@@ -235,6 +239,7 @@ def train(
                    "writeback_seconds": 0.0, "stall_seconds": 0.0}
     overlap_slices = 0
     overflow_redispatches = 0
+    save_events = []
     preempted_at: Optional[int] = None
     progress.emit("start", start)
 
@@ -248,6 +253,7 @@ def train(
         nonlocal save_seconds, d2h_bytes, hashed_bytes
         nonlocal overlap_slices, overflow_redispatches
         s = mgr.last_save_stats
+        save_events.append(dict(s))
         for k in save_timing:
             save_timing[k] += s.get(k, 0.0)
         d2h_bytes += s.get("d2h_bytes", 0)
@@ -270,6 +276,7 @@ def train(
                         tracker.set_reference(u, ov.last_snapshot_fps[u])
 
     for step in range(start, total_steps):
+        t_step = time.time()
         raw = data.peek(step)
         data.state.step = step + 1
         state, metrics = train_step(state, to_batch(raw))
@@ -281,6 +288,7 @@ def train(
             if done is not None:
                 absorb_event(done)
         loss = float(metrics["loss"])
+        step_seconds.append(time.time() - t_step)
         losses.append((step, loss))
         progress.emit("step", step + 1)
         if fail_step is not None and step + 1 == fail_step:
@@ -322,6 +330,12 @@ def train(
         if (step + 1) % ckpt_interval == 0:
             scores = tracker.scores(state["params"]) if tracker else None
             if ov is not None:
+                if ov.active:
+                    # the previous event is still spreading: it commits
+                    # first (events are FIFO) and is accounted here
+                    done = ov.finish()
+                    if done is not None:
+                        absorb_event(done)
                 # Snapshot + decisions now (this is the last moment the
                 # pre-donation state is intact); staging, writes, and the
                 # commit ride the next ticks.
@@ -385,6 +399,7 @@ def train(
         "preempted_at": preempted_at,
         "final_loss": losses[-1][1] if losses else float("nan"),
         "losses": losses,
+        "step_seconds": step_seconds,
         "train_seconds": total,
         "save_seconds": save_seconds,
         "ckpt_time_fraction": save_seconds / total if total else 0.0,
@@ -412,6 +427,10 @@ def train(
         "scrub_report": scrub_report,
         # sharded-save accounting (1 = classic global-array save)
         "shard_participants": shard_participants,
+        # every committed event's last_save_stats, in commit order
+        "save_events": save_events,
+        # the resume's last_restore_stats (None for a fresh start)
+        "restore": dict(mgr.last_restore_stats) if resume else None,
     }
 
 
@@ -508,6 +527,7 @@ def main() -> None:
     ap.add_argument("--log-csv")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
     out = train(arch=args.arch, reduced=args.smoke, total_steps=args.steps,
                 batch=args.batch, seq_len=args.seq_len,
                 policy_name=args.policy, ckpt_interval=args.ckpt_interval,

@@ -12,19 +12,13 @@ wire it by stacking block groups as stages.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
-
-# jax < 0.6 has no shard_map varying-mesh-axes typing (and no pvary); the
-# identity is the correct shim there — carries are already untyped.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 
 def pipeline_apply(
@@ -46,8 +40,10 @@ def pipeline_apply(
         mb_shape = xs.shape[1:]
         # Mark carries as device-varying along the stage axis up front so the
         # fori_loop carry types stay stable (shard_map vma typing).
-        state = _pvary(jnp.zeros(mb_shape, xs.dtype), (axis,))
-        outs = _pvary(jnp.zeros((m,) + mb_shape, xs.dtype), (axis,))
+        state = jax.lax.pcast(jnp.zeros(mb_shape, xs.dtype), (axis,),
+                              to="varying")
+        outs = jax.lax.pcast(jnp.zeros((m,) + mb_shape, xs.dtype), (axis,),
+                             to="varying")
 
         def tick(t, carry):
             state, outs = carry
@@ -76,7 +72,7 @@ def pipeline_apply(
             jnp.where(idx == 0, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -40,10 +40,8 @@ def test_scan_trip_count_multiplies():
     acc = account(c.as_text(), num_devices=1)
     expected = layers * 2 * n * n
     assert abs(acc.flops - expected) / expected < 0.2, acc.flops
-    # raw cost_analysis counts the body once (the known undercount);
-    # jax < 0.5 returns a per-computation list rather than a dict
-    ca = c.cost_analysis()
-    raw = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    # raw cost_analysis counts the body once (the known undercount)
+    raw = c.cost_analysis()["flops"]
     assert raw < expected / 2
 
 

@@ -56,12 +56,8 @@ def test_vocab_to_model():
 
 
 def test_data_sharding_batch_divisibility():
-    import jax
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((1, 1), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:  # jax < 0.5: Auto is the (only) behavior
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     s = shd.data_sharding((8, 16), mesh)
     assert s.spec == P(("data",), None) or s.spec == P(None, None) \
         or s.spec == P((), None) or True  # 1-device mesh: anything legal
