@@ -41,6 +41,7 @@ lane's drain, i.e. the durability barrier or close).
 """
 from __future__ import annotations
 
+import contextvars
 import glob
 import os
 import pickle
@@ -750,7 +751,7 @@ class TransferPool:
             try:
                 if item is _SENTINEL:
                     return
-                lane, fn, args, kwargs, pending = item
+                lane, ctx, fn, args, kwargs, pending = item
                 _ACTIVE_LANE.lane = lane
                 try:
                     # Fault-injection seam: ``pool:<lane>`` fires before
@@ -758,7 +759,7 @@ class TransferPool:
                     # death; surfaces on the lane's drain like any other
                     # transfer failure).  No-op unless armed.
                     crash_point(f"pool:{lane}")
-                    pending._value = fn(*args, **kwargs)
+                    pending._value = ctx.run(fn, *args, **kwargs)
                 except BaseException as e:  # noqa: BLE001
                     pending._error = e
                     with self._cond:
@@ -784,8 +785,11 @@ class TransferPool:
         # The put happens outside the lock so a full queue still drains
         # (workers never take the condition while executing user work for
         # longer than a counter update).  close() waits on the counters,
-        # not the queue, so this item can never be stranded.
-        self._q.put((lane, fn, args, kwargs, pending))
+        # not the queue, so this item can never be stranded.  The task
+        # runs in a copy of the submitter's context: a checkpoint event's
+        # spans (repro.checkpoint.tracing) follow its writes to the lane.
+        self._q.put((lane, contextvars.copy_context(), fn, args, kwargs,
+                     pending))
         return pending
 
     def submit_task(self, lane: str, fn_id: str, *args) -> PendingResult:

@@ -72,7 +72,7 @@ from typing import (TYPE_CHECKING, Any, Dict, Iterable, Iterator, Optional,
 
 import msgpack
 
-from repro.checkpoint import compression, faults, serial
+from repro.checkpoint import compression, faults, serial, tracing
 from repro.checkpoint import fingerprint as fputil
 from repro.checkpoint.async_io import INLINE_DISPATCH, IoDispatch
 from repro.checkpoint.backends import StorageBackend, make_backend
@@ -556,9 +556,10 @@ class ChunkStore:
         return dict(info)
 
     def _write_object(self, digest: str, env: Dict[str, Any]) -> int:
-        blob = msgpack.packb(env, use_bin_type=True)
-        faults.crash_point("object_write")
-        self.backend.write(digest, blob)
+        with tracing.span("ckpt.write.store"):
+            blob = msgpack.packb(env, use_bin_type=True)
+            faults.crash_point("object_write")
+            self.backend.write(digest, blob)
         with self._lock:
             self._info[digest] = {"stored": env["format"],
                                   "base": env.get("base"),
@@ -820,9 +821,10 @@ class ChunkStore:
         # Compression runs through the dispatch: inline under the thread
         # backend (same workers.py code), in a subprocess worker under the
         # process backend — identical bytes either way.
-        full_payload = canon if codec == "none" else \
-            self.dispatch.call("encode_chunk_items",
-                               serial.tree_to_items(tree), {}, codec)
+        with tracing.span("ckpt.write.encode"):
+            full_payload = canon if codec == "none" else \
+                self.dispatch.call("encode_chunk_items",
+                                   serial.tree_to_items(tree), {}, codec)
 
         # Try a delta against the previous chunk's *full* base.  Lossy
         # codecs are excluded: a delta restores the exact canonical bytes,
@@ -845,9 +847,10 @@ class ChunkStore:
                 # codec this environment lacks): degrade to a full write
                 base_canon = None
             if base_canon is not None:
-                dblob = self.dispatch.call(
-                    "delta_encode", canon, base_canon,
-                    "zstd" if codec == "zstd" else "none")
+                with tracing.span("ckpt.write.encode"):
+                    dblob = self.dispatch.call(
+                        "delta_encode", canon, base_canon,
+                        "zstd" if codec == "zstd" else "none")
                 if len(dblob) < self.delta_ratio * len(full_payload):
                     nbytes = self._write_object(digest, {
                         "v": OBJECT_VERSION, "format": "delta",
@@ -898,11 +901,12 @@ class ChunkStore:
                 # the process backend).  Leaves arrive in flatten order,
                 # so the payload is byte-identical to
                 # ``encode_chunk(rebuild_full(leaves))``.
-                items = [(l.path, tuple(l.shape), l.dtype,
-                          bytes(l.data[:l.nbytes]))
-                         for l in packet.leaves]
-                payload = self.dispatch.call("encode_chunk_items", items,
-                                             {}, self.codec)
+                with tracing.span("ckpt.write.encode"):
+                    items = [(l.path, tuple(l.shape), l.dtype,
+                              bytes(l.data[:l.nbytes]))
+                             for l in packet.leaves]
+                    payload = self.dispatch.call("encode_chunk_items",
+                                                 items, {}, self.codec)
                 env = {"v": OBJECT_VERSION, "format": "full",
                        "codec": self.codec, "base": None, "payload": payload,
                        "fp": packet.table}
@@ -916,18 +920,20 @@ class ChunkStore:
                                 nbytes=nbytes, digest=digest, stored="full",
                                 delta_base=None)
             assert packet.base_digest, "block delta requires a base"
-            records = [{"name": l.path, "shape": list(l.shape),
-                        "dtype": l.dtype, "nbytes": l.nbytes,
-                        "block": l.block_bytes,
-                        "idx": [] if l.idx is None else list(map(int, l.idx)),
-                        # staged payloads arrive as memoryviews into a
-                        # staging slot; materialize on THIS (writer) thread
-                        "data": (l.data if isinstance(l.data, bytes)
-                                 else bytes(l.data))}
-                       for l in packet.leaves if l.idx is None or len(l.idx)]
-            blob = self.dispatch.call(
-                "block_delta_encode", records,
-                "zstd" if self.codec == "zstd" else "none")
+            with tracing.span("ckpt.write.encode"):
+                records = [
+                    {"name": l.path, "shape": list(l.shape),
+                     "dtype": l.dtype, "nbytes": l.nbytes,
+                     "block": l.block_bytes,
+                     "idx": [] if l.idx is None else list(map(int, l.idx)),
+                     # staged payloads arrive as memoryviews into a
+                     # staging slot; materialize on THIS (writer) thread
+                     "data": (l.data if isinstance(l.data, bytes)
+                              else bytes(l.data))}
+                    for l in packet.leaves if l.idx is None or len(l.idx)]
+                blob = self.dispatch.call(
+                    "block_delta_encode", records,
+                    "zstd" if self.codec == "zstd" else "none")
             env = {"v": OBJECT_VERSION, "format": "block_delta",
                    "base": packet.base_digest, "payload": blob,
                    "fp": packet.table}

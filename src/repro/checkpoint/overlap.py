@@ -56,7 +56,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
-import time
 from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -64,10 +63,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint import faults
+from repro.checkpoint import faults, tracing
 from repro.checkpoint import fingerprint as fputil
 from repro.checkpoint.async_io import PendingResult, StagingArena
-from repro.checkpoint.saver import CheckpointManager
+from repro.checkpoint.saver import CheckpointManager, tables_to_host
 from repro.checkpoint.sharded import _usable_prev
 from repro.checkpoint.serial import flatten_with_paths
 from repro.core.manifest import Manifest
@@ -147,7 +146,7 @@ class _Event:
     durability_barrier: Optional[bool]
     queue: List[_StagedUnit]
     per_slice: int
-    wall0: float
+    trace: tracing.Event
     resolved: Dict[Tuple[str, str], Any] = dataclasses.field(
         default_factory=dict)
     pending: Dict[Tuple[str, str], PendingResult] = dataclasses.field(
@@ -157,10 +156,6 @@ class _Event:
     snapshot_fps: Dict[str, List[LeafFP]] = dataclasses.field(
         default_factory=dict)
     workers0: Any = None
-    begin_seconds: float = 0.0
-    stage_seconds: float = 0.0
-    writeback_seconds: float = 0.0
-    stall_seconds: float = 0.0
     slices: int = 0
     d2h_bytes: int = 0
     staged_bytes: int = 0
@@ -228,12 +223,20 @@ class OverlappedSaver:
         needs only its own staged buffers."""
         if self._event is not None:
             self.finish()
+        trace = tracing.Event()
+        with tracing.active(trace), tracing.span("ckpt.save.begin"):
+            self._event = self._begin(trace, state, int(step), meta=meta,
+                                      drift_scores=drift_scores,
+                                      units=units,
+                                      durability_barrier=durability_barrier)
+
+    def _begin(self, trace: tracing.Event, state: Dict[str, PyTree],
+               step: int, *, meta, drift_scores, units,
+               durability_barrier) -> _Event:
         mgr = self.mgr
-        t0 = time.time()
         pool = mgr.transfer_pool
         workers0 = (pool.dispatch.stats() if pool is not None else None)
         mgr.store.reset_stats()
-        step = int(step)
         event_index = mgr.reserve_event_index()
         ctx = PolicyContext(event_index=event_index, step=step,
                             drift_scores=drift_scores)
@@ -251,15 +254,12 @@ class OverlappedSaver:
                     prev_step=prev.step if prev else None,
                     entries=entries, selected=selected, meta=meta,
                     durability_barrier=durability_barrier, queue=[],
-                    per_slice=1, wall0=t0, workers0=workers0)
+                    per_slice=1, trace=trace, workers0=workers0)
         for name in selected:
             drift = (drift_scores or {}).get(name)
             for kind in ("weights", "opt"):
-                tree = (mgr.registry.extract_unit(state["params"], name)
-                        if kind == "weights" else
-                        mgr.registry.extract_opt_unit(state["opt"], name))
                 pref = mgr._prev_entry(prev, name, kind)
-                self._begin_unit(ev, name, kind, tree, pref, drift)
+                self._begin_unit(ev, name, kind, state, pref, drift)
         # Batch-resolve the deferred store-wide dedup probes: one
         # concurrent ``store.has`` per still-queued unit (see
         # ``_begin_unit``).  Same decision, same order of authority —
@@ -284,68 +284,73 @@ class OverlappedSaver:
         # has been written, no manifest moved — the canonical "died with
         # a whole event in flight" drill.
         faults.crash_point("snapshot_overlap")
-        self._event = ev
-        ev.begin_seconds = time.time() - t0
-        ev.stall_seconds += ev.begin_seconds
+        return ev
 
-    def _begin_unit(self, ev: _Event, name: str, kind: str, tree: PyTree,
-                    pref, drift: Optional[float]) -> None:
+    def _begin_unit(self, ev: _Event, name: str, kind: str,
+                    state: Dict[str, PyTree], pref,
+                    drift: Optional[float]) -> None:
         mgr = self.mgr
         bb = mgr.fp_block_bytes
-        flat = flatten_with_paths(tree)
-        arrs = [jnp.asarray(a) for _, a in flat]
-        metas = fputil.meta_table(tree, bb)
-        nb_total = sum(m.n_blocks for m in metas)
-        ev.blocks_total += nb_total
+        attrs = {"unit": name, "kind": kind}
+        with tracing.span("ckpt.save.fingerprint", **attrs):
+            tree = mgr._extract(state, name, kind)
+            flat = flatten_with_paths(tree)
+            arrs = [jnp.asarray(a) for _, a in flat]
+            metas = fputil.meta_table(tree, bb)
+            nb_total = sum(m.n_blocks for m in metas)
+            ev.blocks_total += nb_total
 
-        # Delta base planned from structure alone (meta_matches never
-        # reads checksums) so the fused kernel can compare against it in
-        # the same pass that fingerprints.
-        base_digest, base_tbl = mgr._delta_base(name, kind, pref, metas)
-        results = None
-        if base_tbl is not None:
-            caps = [self.predictor.predict(name, kind, m.path, m.n_blocks,
-                                           drift) for m in metas]
-            results = bgather.gather_tree_dirty(
-                arrs, [np.asarray(b.fp) for b in base_tbl], caps,
-                block_bytes=bb, interpret=self.interpret)
-            cur = [LeafFP(path=m.path, shape=m.shape, dtype=m.dtype,
-                          nbytes=m.nbytes, block_bytes=bb,
-                          fp=r.fp, sumsq=r.sumsq)
-                   for m, r in zip(metas, results)]
-        else:
-            cur = bfp.fingerprint_tree(tree, block_bytes=bb,
-                                       interpret=self.interpret)
-        path = bfp.kernel_path(self.interpret)
-        ev.kernel_leaves[f"fp_leaves_{path}"] += len(arrs)
-        if results is not None:
-            ev.kernel_leaves[f"gather_leaves_{path}"] += len(arrs)
-        faults.crash_point("fingerprint")
+            # Delta base planned from structure alone (meta_matches never
+            # reads checksums) so the fused kernel can compare against it
+            # in the same pass that fingerprints.
+            base_digest, base_tbl = mgr._delta_base(name, kind, pref, metas)
+            results = None
+            if base_tbl is not None:
+                caps = [self.predictor.predict(name, kind, m.path,
+                                               m.n_blocks, drift)
+                        for m in metas]
+                results = bgather.gather_tree_dirty(
+                    arrs, [np.asarray(b.fp) for b in base_tbl], caps,
+                    block_bytes=bb, interpret=self.interpret)
+                cur = [LeafFP(path=m.path, shape=m.shape, dtype=m.dtype,
+                              nbytes=m.nbytes, block_bytes=bb,
+                              fp=r.fp, sumsq=r.sumsq)
+                       for m, r in zip(metas, results)]
+            else:
+                cur = bfp.fingerprint_tree(tree, block_bytes=bb,
+                                           interpret=self.interpret)
+            path = bfp.kernel_path(self.interpret)
+            ev.kernel_leaves[f"fp_leaves_{path}"] += len(arrs)
+            if results is not None:
+                ev.kernel_leaves[f"gather_leaves_{path}"] += len(arrs)
+            faults.crash_point("fingerprint")
 
-        # The fingerprint tables are ~0.02% of the data: fetching them
-        # synchronously is what every decision below hangs off.
-        host = bfp.tree_to_host(cur)
-        tblob = fputil.pack_table(host)
-        digest = fputil.fp_digest(tblob)
-        logical = sum(l.nbytes for l in host)
-        ev.new_fps[(name, kind)] = host
-        if kind == "weights":
-            ev.snapshot_fps[name] = host
+            # The fingerprint tables are ~0.02% of the data: fetching
+            # them synchronously is what every decision below hangs off.
+            host = tables_to_host(cur)
+            tblob = fputil.pack_table(host)
+            digest = fputil.fp_digest(tblob)
+            logical = sum(l.nbytes for l in host)
+            ev.new_fps[(name, kind)] = host
+            if kind == "weights":
+                ev.snapshot_fps[name] = host
 
-        # Decision order — byte-for-byte the sync ``_save_unit_fp`` tree.
-        ref_fp = mgr._fp_refs.get((name, kind))
-        if ref_fp is None and pref is not None and pref.digest:
-            ref_fp = mgr.store.load_fp_table(pref.digest)
-        if (ref_fp is not None and pref is not None and pref.digest
-                and bfp.leaves_match(host, ref_fp)):
-            # Unchanged: a predicted-dirty gather (if any) is discarded
-            # on device — the clean-misprediction that costs nothing.
-            ev.resolved[(name, kind)] = mgr.store.note_dedup(
-                ev.step, name, kind, pref.digest, prev_ref=pref,
-                logical_bytes=logical)
-            for m in metas:
-                self.predictor.observe(name, kind, m.path, 0)
-            return
+            # Decision order — byte-for-byte the sync ``_save_unit_fp``
+            # tree.
+            ref_fp = mgr._fp_refs.get((name, kind))
+            if ref_fp is None and pref is not None and pref.digest:
+                ref_fp = mgr.store.load_fp_table(pref.digest)
+            if (ref_fp is not None and pref is not None and pref.digest
+                    and bfp.leaves_match(host, ref_fp)):
+                # Unchanged: a predicted-dirty gather (if any) is
+                # discarded on device — the clean-misprediction that costs
+                # nothing.
+                ev.resolved[(name, kind)] = mgr.store.note_dedup(
+                    ev.step, name, kind, pref.digest, prev_ref=pref,
+                    logical_bytes=logical)
+                for m in metas:
+                    self.predictor.observe(name, kind, m.path, 0)
+                return
         # The store-wide dedup probe (``store.has``) is deferred: the
         # unit stages eagerly and ``begin`` batch-resolves every probe
         # concurrently through the transfer pool — against a remote
@@ -355,52 +360,59 @@ class OverlappedSaver:
         # staged copies are discarded — a dedup-misprediction that
         # costs device copies, never correctness).
 
-        use_delta = base_tbl is not None
-        counts: List[int] = []
-        if use_delta:
-            counts = [int(c) for c in jax.device_get(
-                [r.count for r in results])]
-            if sum(counts) > mgr.fp_max_dirty_frac * nb_total:
-                use_delta = False
+        # The dirty counts and indices are fetched here; the payload
+        # copies are only started (``copy_to_host_async``), and ticks
+        # wait for them in ``ckpt.save.pack``.
+        with tracing.span("ckpt.save.d2h", **attrs):
+            use_delta = base_tbl is not None
+            counts: List[int] = []
+            if use_delta:
+                counts = [int(c) for c in jax.device_get(
+                    [r.count for r in results])]
+                tracing.count("d2h_calls")
+                if sum(counts) > mgr.fp_max_dirty_frac * nb_total:
+                    use_delta = False
 
-        leaves: List[_StagedLeaf] = []
-        if use_delta:
-            for i, (m, r, c) in enumerate(zip(metas, results, counts)):
-                if c > r.capacity:
-                    # Under-prediction: the count is authoritative, the
-                    # buffers are live — re-gather at the true size
-                    # before the state is donated.
-                    ev.overflows += 1
-                    self.predictor.overflows += 1
-                    r = bgather.gather_dirty(
-                        arrs[i], np.asarray(base_tbl[i].fp), capacity=c,
-                        block_bytes=bb, interpret=self.interpret)
-                    results[i] = r
-                else:
-                    self.predictor.hits += 1
-                self.predictor.observe(name, kind, m.path, c)
-            idxs = jax.device_get([r.idx for r in results])
-            for m, r, c, idx in zip(metas, results, counts, idxs):
-                dev = r.blocks
-                if c:
-                    # start the D2H now; ticks only collect it
+            leaves: List[_StagedLeaf] = []
+            if use_delta:
+                for i, (m, r, c) in enumerate(zip(metas, results, counts)):
+                    if c > r.capacity:
+                        # Under-prediction: the count is authoritative,
+                        # the buffers are live — re-gather at the true
+                        # size before the state is donated.
+                        ev.overflows += 1
+                        self.predictor.overflows += 1
+                        r = bgather.gather_dirty(
+                            arrs[i], np.asarray(base_tbl[i].fp), capacity=c,
+                            block_bytes=bb, interpret=self.interpret)
+                        results[i] = r
+                    else:
+                        self.predictor.hits += 1
+                    self.predictor.observe(name, kind, m.path, c)
+                idxs = jax.device_get([r.idx for r in results])
+                tracing.count("d2h_calls")
+                for m, r, c, idx in zip(metas, results, counts, idxs):
+                    dev = r.blocks
+                    if c:
+                        # start the D2H now; ticks only collect it
+                        try:
+                            dev.copy_to_host_async()
+                        except AttributeError:  # pragma: no cover - np
+                            pass
+                    leaves.append(_StagedLeaf(meta=m, mode="delta", dev=dev,
+                                              idx=np.asarray(idx[:c]),
+                                              count=c))
+            else:
+                copies = _device_copy(arrs)
+                for dev in copies:
                     try:
                         dev.copy_to_host_async()
                     except AttributeError:  # pragma: no cover - np input
                         pass
-                leaves.append(_StagedLeaf(meta=m, mode="delta", dev=dev,
-                                          idx=np.asarray(idx[:c]), count=c))
-        else:
-            copies = _device_copy(arrs)
-            for dev in copies:
-                try:
-                    dev.copy_to_host_async()
-                except AttributeError:  # pragma: no cover - np input
-                    pass
-            for m, dev in zip(metas, copies):
-                leaves.append(_StagedLeaf(meta=m, mode="full", dev=dev))
-            for m in metas:
-                self.predictor.observe(name, kind, m.path, m.n_blocks)
+                for m, dev in zip(metas, copies):
+                    leaves.append(_StagedLeaf(meta=m, mode="full", dev=dev))
+                for m in metas:
+                    self.predictor.observe(name, kind, m.path, m.n_blocks)
         ev.queue.append(_StagedUnit(
             name=name, kind=kind, pref=pref, digest=digest, tblob=tblob,
             logical=logical, nb_total=nb_total, full=not use_delta,
@@ -418,16 +430,15 @@ class OverlappedSaver:
         ev = self._event
         if ev is None:
             return None
-        t0 = time.time()
         faults.crash_point("spread_slice")
-        if ev.queue:
-            for _ in range(min(ev.per_slice, len(ev.queue))):
-                self._stage_and_submit(ev, ev.queue.pop(0))
-            ev.slices += 1
-            ev.stage_seconds += time.time() - t0
-            ev.stall_seconds += time.time() - t0
-            return None
-        return self._commit(ev, t0)
+        with tracing.active(ev.trace):
+            if ev.queue:
+                with tracing.span("ckpt.save.slice"):
+                    for _ in range(min(ev.per_slice, len(ev.queue))):
+                        self._stage_and_submit(ev, ev.queue.pop(0))
+                ev.slices += 1
+                return None
+            return self._commit(ev)
 
     def finish(self) -> Optional[Manifest]:
         """Run the event to completion NOW (sync point: preemption saves,
@@ -435,19 +446,26 @@ class OverlappedSaver:
         ev = self._event
         if ev is None:
             return None
-        t0 = time.time()
-        while ev.queue:
-            faults.crash_point("spread_slice")
-            self._stage_and_submit(ev, ev.queue.pop(0))
-        ev.slices += 1
-        ev.stage_seconds += time.time() - t0
-        return self._commit(ev, t0)
+        with tracing.active(ev.trace):
+            with tracing.span("ckpt.save.slice"):
+                while ev.queue:
+                    faults.crash_point("spread_slice")
+                    self._stage_and_submit(ev, ev.queue.pop(0))
+            ev.slices += 1
+            return self._commit(ev)
 
     @property
     def active(self) -> bool:
         return self._event is not None
 
     def _stage_and_submit(self, ev: _Event, unit: _StagedUnit) -> None:
+        """Wait for the unit's device-to-host copies, pack them into a
+        staging slot and hand the packet to a writer lane: the
+        ``ckpt.save.pack`` span."""
+        with tracing.span("ckpt.save.pack", unit=unit.name, kind=unit.kind):
+            self._stage_unit(ev, unit)
+
+    def _stage_unit(self, ev: _Event, unit: _StagedUnit) -> None:
         mgr = self.mgr
         total = 0
         for leaf in unit.leaves:
@@ -464,6 +482,7 @@ class OverlappedSaver:
                     data: Any = b""
                     if leaf.count:
                         arr = np.asarray(leaf.dev)[:leaf.count]
+                        tracing.count("d2h_calls")
                         data = slot.pack(_byte_view(arr))
                         ev.d2h_bytes += data.nbytes
                         ev.blocks_moved += leaf.count
@@ -473,6 +492,7 @@ class OverlappedSaver:
                         idx=leaf.idx, data=data))
                 else:
                     arr = np.asarray(leaf.dev)
+                    tracing.count("d2h_calls")
                     data = slot.pack(_byte_view(arr))
                     ev.d2h_bytes += data.nbytes
                     ev.blocks_moved += m.n_blocks
@@ -522,51 +542,56 @@ class OverlappedSaver:
                                        packet, prev_ref=unit.pref)
 
     # ------------------------------------------------------------ commit
-    def _commit(self, ev: _Event, slice_t0: float) -> Manifest:
-        """Drain, commit, account.  ``slice_t0`` is when the completing
-        tick/finish started blocking the caller: everything from there to
-        the end of the commit is stall."""
+    def _commit(self, ev: _Event) -> Manifest:
+        """Drain, commit, account: the ``ckpt.save`` span of the call that
+        completes the event.  The event's stall is that span plus its
+        ``ckpt.save.begin`` and ``ckpt.save.slice`` spans."""
         mgr = self.mgr
-        t0 = time.time()
-        if mgr.writer is not None:
-            mgr.writer.drain()
-            for key, p in ev.pending.items():
-                ev.resolved[key] = p.result()
-        ev.writeback_seconds = time.time() - t0
+        with tracing.span("ckpt.save"):
+            with tracing.span("ckpt.save.drain"):
+                if mgr.writer is not None:
+                    mgr.writer.drain()
+                    for key, p in ev.pending.items():
+                        ev.resolved[key] = p.result()
 
-        latest = mgr.manifests.load()
-        latest_step = latest.step if latest is not None else None
-        if latest_step != ev.prev_step:
-            # A direct save committed mid-event (callers should finish()
-            # first).  The event's own objects are content-addressed and
-            # final; only the carried-forward entries must re-anchor.
-            log.warning(
-                "manifest for step %s committed while overlapped event "
-                "for step %s was in flight; re-anchoring carried entries",
-                latest_step, ev.step)
-            lat = _usable_prev(latest)
-            base_entries = ({u: dict(k) for u, k in lat.entries.items()}
-                            if lat else {})
-        else:
-            base_entries = ev.entries
-        for (name, kind), ref in ev.resolved.items():
-            base_entries.setdefault(name, {})[kind] = ref
-        manifest, storage = mgr._commit_event(
-            step=ev.step, entries=base_entries, selected=ev.selected,
-            meta=ev.meta, new_fps=ev.new_fps,
-            event_index=ev.event_index,
-            durability_barrier=ev.durability_barrier)
-        ev.stall_seconds += time.time() - slice_t0
+            latest = mgr.manifests.load()
+            latest_step = latest.step if latest is not None else None
+            if latest_step != ev.prev_step:
+                # A direct save committed mid-event (callers should
+                # finish() first).  The event's own objects are
+                # content-addressed and final; only the carried-forward
+                # entries must re-anchor.
+                log.warning(
+                    "manifest for step %s committed while overlapped event "
+                    "for step %s was in flight; re-anchoring carried "
+                    "entries", latest_step, ev.step)
+                lat = _usable_prev(latest)
+                base_entries = ({u: dict(k) for u, k in lat.entries.items()}
+                                if lat else {})
+            else:
+                base_entries = ev.entries
+            for (name, kind), ref in ev.resolved.items():
+                base_entries.setdefault(name, {})[kind] = ref
+            manifest, storage = mgr._commit_event(
+                step=ev.step, entries=base_entries, selected=ev.selected,
+                meta=ev.meta, new_fps=ev.new_fps,
+                event_index=ev.event_index,
+                durability_barrier=ev.durability_barrier)
+        total = ev.trace.seconds()
+        stages, counters = ev.trace.fold()
         stats = mgr._event_stats(
             step=ev.step, selected=ev.selected, d2h_bytes=ev.d2h_bytes,
             blocks_moved=ev.blocks_moved, blocks_total=ev.blocks_total,
             storage=storage, workers0=ev.workers0,
-            kernel_leaves=ev.kernel_leaves,
-            timings={"snapshot_seconds": ev.begin_seconds,
-                     "stage_seconds": ev.stage_seconds,
-                     "writeback_seconds": ev.writeback_seconds,
-                     "stall_seconds": ev.stall_seconds,
-                     "total_seconds": time.time() - ev.wall0})
+            kernel_leaves=ev.kernel_leaves, stages=stages,
+            counters=counters,
+            timings={"snapshot_seconds": stages["ckpt.save.begin"],
+                     "stage_seconds": stages.get("ckpt.save.slice", 0.0),
+                     "writeback_seconds": stages["ckpt.save.drain"],
+                     "stall_seconds": (stages["ckpt.save.begin"]
+                                       + stages.get("ckpt.save.slice", 0.0)
+                                       + stages["ckpt.save"]),
+                     "total_seconds": total})
         stats["save_mode"] = "overlapped"
         stats["spread_steps"] = self.spread_steps
         stats["spread_slices"] = ev.slices

@@ -51,16 +51,15 @@ Restore path (= the paper's merge, done lazily — see docs/restore.md):
 from __future__ import annotations
 
 import logging
-import time
 from collections import Counter
 from pathlib import Path
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint import faults
+from repro.checkpoint import faults, tracing
 from repro.checkpoint import fingerprint as fputil
 from repro.checkpoint.async_io import (
     WORKER_BACKENDS,
@@ -92,6 +91,14 @@ from repro.kernels import block_fp as bfp
 log = logging.getLogger("repro.checkpoint")
 
 PyTree = Any
+
+
+def tables_to_host(cur: List[bfp.LeafFP]) -> List[bfp.LeafFP]:
+    """``block_fp.tree_to_host``, counted: one ``device_get`` per
+    fingerprint vector (each leaf's ``fp`` and ``sumsq``)."""
+    tracing.count("d2h_calls",
+                  sum(1 + (l.sumsq is not None) for l in cur))
+    return bfp.tree_to_host(cur)
 
 
 class CheckpointManager:
@@ -279,7 +286,27 @@ class CheckpointManager:
         soon as objects are on the fast tier — the preemption hot save —
         and True waits the spill lane down first.
         """
-        t0 = time.time()
+        trace = tracing.Event()
+        with tracing.active(trace), tracing.span("ckpt.save"):
+            manifest, counts = self._save_event(
+                state, step=step, meta=meta, drift_scores=drift_scores,
+                units=units, durability_barrier=durability_barrier)
+        stages, counters = trace.fold()
+        # The synchronous save blocks the caller end to end: the stall is
+        # the whole event (the overlapped saver is where they diverge).
+        self.last_save_stats = self._event_stats(
+            **counts, stages=stages, counters=counters,
+            timings={"snapshot_seconds": stages["ckpt.save.snapshot"],
+                     "stage_seconds": 0.0,
+                     "writeback_seconds": stages["ckpt.save.drain"],
+                     "stall_seconds": stages["ckpt.save"],
+                     "total_seconds": stages["ckpt.save"]})
+        return manifest
+
+    def _save_event(self, state, *, step, meta, drift_scores, units,
+                    durability_barrier) -> Tuple[Manifest, Dict[str, Any]]:
+        """The body of ``save`` inside its ``ckpt.save`` span: the
+        manifest and the event's counts for ``_event_stats``."""
         pool = self.transfer_pool
         workers0 = (pool.dispatch.stats() if pool is not None else None)
         step = int(state["step"]) if step is None else int(step)
@@ -299,9 +326,6 @@ class CheckpointManager:
         entries: Dict[str, Dict[str, ChunkRef]] = (
             {u: dict(k) for u, k in prev.entries.items()} if prev else {})
 
-        def prev_entry(name: str, kind: str) -> Optional[ChunkRef]:
-            return self._prev_entry(prev, name, kind)
-
         # Snapshot selected units to host (sync) and enqueue writes (async).
         # The fingerprint path replaces the full device_get with a device
         # compare + dirty-block gather; while the writer threads encode and
@@ -315,63 +339,64 @@ class CheckpointManager:
         kernel_leaves: Counter = Counter()
         pending: Dict[Tuple[str, str], PendingResult] = {}
         new_fps: Dict[Tuple[str, str], Any] = {}
-        for name in selected:
-            for kind in ("weights", "opt"):
-                tree = (self.registry.extract_unit(state["params"], name)
-                        if kind == "weights" else
-                        self.registry.extract_opt_unit(state["opt"], name))
-                pref = prev_entry(name, kind)
-                if not self.fingerprint:
-                    host = jax.device_get(tree)
-                    faults.crash_point("gather")
-                    d2h_bytes += sum(np.asarray(x).nbytes
-                                     for x in jax.tree.leaves(host))
-                    if self.writer is not None:
-                        pending[(name, kind)] = self.writer.submit(
-                            self.store.write, step, name, kind, host,
-                            prev_ref=pref)
+        with tracing.span("ckpt.save.snapshot"):
+            for name in selected:
+                for kind in ("weights", "opt"):
+                    pref = self._prev_entry(prev, name, kind)
+                    if not self.fingerprint:
+                        with tracing.span("ckpt.save.d2h", unit=name,
+                                          kind=kind):
+                            host = jax.device_get(
+                                self._extract(state, name, kind))
+                            tracing.count("d2h_calls")
+                        faults.crash_point("gather")
+                        d2h_bytes += sum(np.asarray(x).nbytes
+                                         for x in jax.tree.leaves(host))
+                        with tracing.span("ckpt.save.pack", unit=name,
+                                          kind=kind):
+                            if self.writer is not None:
+                                pending[(name, kind)] = self.writer.submit(
+                                    self.store.write, step, name, kind,
+                                    host, prev_ref=pref)
+                            else:
+                                entries.setdefault(name, {})[kind] = \
+                                    self.store.write(step, name, kind, host,
+                                                     prev_ref=pref)
+                        continue
+                    res, ustat, cur = self._save_unit_fp(
+                        step, name, kind,
+                        lambda: self._extract(state, name, kind), pref)
+                    d2h_bytes += ustat["d2h_bytes"]
+                    blocks_moved += ustat["blocks_moved"]
+                    blocks_total += ustat["blocks_total"]
+                    kernel_leaves.update(ustat["kernel_leaves"])
+                    new_fps[(name, kind)] = cur
+                    if isinstance(res, PendingResult):
+                        pending[(name, kind)] = res
                     else:
-                        entries.setdefault(name, {})[kind] = self.store.write(
-                            step, name, kind, host, prev_ref=pref)
-                    continue
-                res, ustat, cur = self._save_unit_fp(step, name, kind,
-                                                     tree, pref)
-                d2h_bytes += ustat["d2h_bytes"]
-                blocks_moved += ustat["blocks_moved"]
-                blocks_total += ustat["blocks_total"]
-                kernel_leaves.update(ustat["kernel_leaves"])
-                new_fps[(name, kind)] = cur
-                if isinstance(res, PendingResult):
-                    pending[(name, kind)] = res
-                else:
-                    entries.setdefault(name, {})[kind] = res
-        t_snapshot = time.time() - t0
+                        entries.setdefault(name, {})[kind] = res
 
         # All chunks must land (on the fast tier at least) before the
         # manifest commits; the optional spill barrier upgrades that to
         # "on the durable tier".
-        t_wb = time.time()
-        if self.writer is not None:
-            self.writer.drain()
-            for (name, kind), p in pending.items():
-                entries.setdefault(name, {})[kind] = p.result()
-        t_writeback = time.time() - t_wb
+        with tracing.span("ckpt.save.drain"):
+            if self.writer is not None:
+                self.writer.drain()
+                for (name, kind), p in pending.items():
+                    entries.setdefault(name, {})[kind] = p.result()
         manifest, storage = self._commit_event(
             step=step, entries=entries, selected=selected, meta=meta,
             new_fps=new_fps, durability_barrier=durability_barrier)
-        total = time.time() - t0
-        # The synchronous save blocks the caller end to end: the stall is
-        # the whole event (the overlapped saver is where they diverge).
-        self.last_save_stats = self._event_stats(
+        return manifest, dict(
             step=step, selected=selected, d2h_bytes=d2h_bytes,
             blocks_moved=blocks_moved, blocks_total=blocks_total,
-            storage=storage, workers0=workers0, kernel_leaves=kernel_leaves,
-            timings={"snapshot_seconds": t_snapshot,
-                     "stage_seconds": 0.0,
-                     "writeback_seconds": t_writeback,
-                     "stall_seconds": total,
-                     "total_seconds": total})
-        return manifest
+            storage=storage, workers0=workers0, kernel_leaves=kernel_leaves)
+
+    def _extract(self, state: Dict[str, PyTree], name: str,
+                 kind: str) -> PyTree:
+        return (self.registry.extract_unit(state["params"], name)
+                if kind == "weights" else
+                self.registry.extract_opt_unit(state["opt"], name))
 
     def _prev_entry(self, prev: Optional[Manifest], name: str,
                     kind: str) -> Optional[ChunkRef]:
@@ -402,48 +427,57 @@ class CheckpointManager:
         counter at selection time, steps before the commit lands); the
         counter itself only ever moves forward.
         """
-        barrier = (self.spill_barrier if durability_barrier is None
-                   else durability_barrier)
-        if barrier:
-            self.store.drain_spill()
-        # The durability record is part of the commit: a reader of this
-        # manifest knows which tier the event's objects were durable on
-        # at commit time (e.g. durable_on="hot" while spill is in flight).
-        storage = self.store.durability()
-        idx = self._event_index if event_index is None else int(event_index)
-        manifest = Manifest(step=step, entries=entries,
-                            meta=dict(meta or {}, event_index=idx,
-                                      policy=self.policy.name,
-                                      storage=storage),
-                            saved_units=list(selected))
-        # Re-saving a step overwrites its manifest file: release the
-        # replaced manifest's references or its objects leak until restart.
-        replaced = self.manifests.load(step)
-        self.manifests.commit(manifest)
-        self.store.incref(manifest.referenced_digests().elements())
-        if replaced is not None:
-            self.store.decref(replaced.referenced_digests().elements())
-        self._event_index = max(self._event_index, idx + 1)
-        # The commit is durable: only now may the fingerprint references
-        # advance (a failed write above raised before reaching here).
-        self._fp_refs.update(new_fps)
-        self.gc()
+        with tracing.span("ckpt.save.commit"):
+            barrier = (self.spill_barrier if durability_barrier is None
+                       else durability_barrier)
+            if barrier:
+                self.store.drain_spill()
+            # The durability record is part of the commit: a reader of
+            # this manifest knows which tier the event's objects were
+            # durable on at commit time (e.g. durable_on="hot" while
+            # spill is in flight).
+            storage = self.store.durability()
+            idx = (self._event_index if event_index is None
+                   else int(event_index))
+            manifest = Manifest(step=step, entries=entries,
+                                meta=dict(meta or {}, event_index=idx,
+                                          policy=self.policy.name,
+                                          storage=storage),
+                                saved_units=list(selected))
+            # Re-saving a step overwrites its manifest file: release the
+            # replaced manifest's references or its objects leak until
+            # restart.
+            replaced = self.manifests.load(step)
+            self.manifests.commit(manifest)
+            self.store.incref(manifest.referenced_digests().elements())
+            if replaced is not None:
+                self.store.decref(replaced.referenced_digests().elements())
+            self._event_index = max(self._event_index, idx + 1)
+            # The commit is durable: only now may the fingerprint
+            # references advance (a failed write above raised before
+            # reaching here).
+            self._fp_refs.update(new_fps)
+            self.gc()
         return manifest, storage
 
     def _event_stats(self, *, step: int, selected, d2h_bytes: int,
                      blocks_moved: int, blocks_total: int, storage,
                      workers0, kernel_leaves: Counter,
+                     stages: Dict[str, float], counters: Counter,
                      timings: Dict[str, float]) -> Dict[str, Any]:
         """Assemble one event's ``last_save_stats`` dict.
 
-        ``timings`` carries the four-way split (docs/perf.md):
-        ``snapshot_seconds`` (device fingerprint/gather dispatch + the
-        decision pass), ``stage_seconds`` (host materialization of staged
-        buffers), ``writeback_seconds`` (encode+write drain), and
-        ``stall_seconds`` — the time the *caller's step loop* actually
-        blocked, the number the zero-stall pipeline exists to shrink.
-        ``kernel_leaves`` counts the leaves each device path fingerprinted
-        and gathered (``fingerprint.KERNEL_LEAF_KEYS``).
+        ``timings`` carries the four-way split (docs/perf.md), each read
+        from the event's spans: ``snapshot_seconds`` (device
+        fingerprint/gather dispatch + the decision pass),
+        ``stage_seconds`` (host materialization of staged buffers),
+        ``writeback_seconds`` (encode+write drain), and ``stall_seconds``
+        — the time the *caller's step loop* actually blocked, the number
+        the zero-stall pipeline exists to shrink.  ``stages`` holds every
+        span's seconds summed over the event, ``counters`` the event's
+        counters (``tracing.Event.fold``).  ``kernel_leaves`` counts the
+        leaves each device path fingerprinted and gathered
+        (``fingerprint.KERNEL_LEAF_KEYS``).
         """
         pool = self.transfer_pool
         io = dict(self.store.stats)
@@ -455,10 +489,11 @@ class CheckpointManager:
             "step": step,
             "selected_units": len(selected),
             "total_units": len(self.registry.units),
-            "snapshot_bytes": d2h_bytes,
             **timings,
+            "stages": stages,
             # transfer/hash accounting for this event (the fingerprint win)
             "d2h_bytes": d2h_bytes,
+            "d2h_calls": counters["d2h_calls"],
             "hashed_bytes": io["hashed_bytes"],
             "dirty_block_frac": dirty_frac,
             # dedup/delta accounting for this event
@@ -494,106 +529,124 @@ class CheckpointManager:
             }
         return stats
 
-    def _save_unit_fp(self, step: int, name: str, kind: str, tree: Any,
+    def _save_unit_fp(self, step: int, name: str, kind: str,
+                      extract: Callable[[], PyTree],
                       pref: Optional[ChunkRef]):
         """Fingerprint save path for one (unit, kind).
 
-        Returns ``(ref_or_pending, stats, cur_fp)`` where stats counts the
-        payload bytes/blocks that actually crossed device->host.  The
-        fingerprint vectors themselves (~0.02% of the data) are not
-        counted as payload."""
+        ``extract`` returns the unit's tree; it is called inside the
+        ``ckpt.save.fingerprint`` span, so slicing the unit out of the
+        stacked state counts there.  Returns ``(ref_or_pending, stats,
+        cur_fp)`` where stats counts the payload bytes/blocks that
+        actually crossed device->host.  The fingerprint vectors
+        themselves (~0.02% of the data) are not counted as payload."""
         bb = self.fp_block_bytes
-        cur = bfp.fingerprint_tree(tree, block_bytes=bb)
-        faults.crash_point("fingerprint")
-        nb_total = sum(l.n_blocks for l in cur)
-        logical = sum(l.nbytes for l in cur)
-        stats = {"d2h_bytes": 0, "blocks_moved": 0, "blocks_total": nb_total,
-                 "kernel_leaves": Counter(
-                     {f"fp_leaves_{bfp.kernel_path()}": len(cur)})}
+        attrs = {"unit": name, "kind": kind}
+        with tracing.span("ckpt.save.fingerprint", **attrs):
+            tree = extract()
+            cur = bfp.fingerprint_tree(tree, block_bytes=bb)
+            faults.crash_point("fingerprint")
+            nb_total = sum(l.n_blocks for l in cur)
+            logical = sum(l.nbytes for l in cur)
+            stats = {"d2h_bytes": 0, "blocks_moved": 0,
+                     "blocks_total": nb_total,
+                     "kernel_leaves": Counter(
+                         {f"fp_leaves_{bfp.kernel_path()}": len(cur)})}
 
-        # Reference vector for the content behind the previous manifest
-        # entry: device-resident from the last commit, or (after a process
-        # restart) the table stored in that object's envelope.
-        ref_fp = self._fp_refs.get((name, kind))
-        if ref_fp is None and pref is not None and pref.digest:
-            ref_fp = self.store.load_fp_table(pref.digest)
-        if (ref_fp is not None and pref is not None and pref.digest
-                and bfp.leaves_match(cur, ref_fp)):
-            # Unchanged: dedup by the stored digest — no payload D2H, no
-            # payload hash, no write.
-            return (self.store.note_dedup(step, name, kind, pref.digest,
-                                          prev_ref=pref,
-                                          logical_bytes=logical),
-                    stats, cur)
+            # Reference vector for the content behind the previous
+            # manifest entry: device-resident from the last commit, or
+            # (after a process restart) the table stored in that object's
+            # envelope.
+            ref_fp = self._fp_refs.get((name, kind))
+            if ref_fp is None and pref is not None and pref.digest:
+                ref_fp = self.store.load_fp_table(pref.digest)
+            if (ref_fp is not None and pref is not None and pref.digest
+                    and bfp.leaves_match(cur, ref_fp)):
+                # Unchanged: dedup by the stored digest — no payload D2H,
+                # no payload hash, no write.
+                return (self.store.note_dedup(step, name, kind, pref.digest,
+                                              prev_ref=pref,
+                                              logical_bytes=logical),
+                        stats, cur)
 
-        host = bfp.tree_to_host(cur)
-        tblob = fputil.pack_table(host)
-        digest = fputil.fp_digest(tblob)
-        if self.store.has(digest):
-            # Content reverted to (or collided with) an object already on
-            # disk: still zero payload transfer.
-            return (self.store.note_dedup(step, name, kind, digest,
-                                          prev_ref=pref,
-                                          logical_bytes=logical),
-                    stats, cur)
+            host = tables_to_host(cur)
+            tblob = fputil.pack_table(host)
+            digest = fputil.fp_digest(tblob)
+            if self.store.has(digest):
+                # Content reverted to (or collided with) an object
+                # already on disk: still zero payload transfer.
+                return (self.store.note_dedup(step, name, kind, digest,
+                                              prev_ref=pref,
+                                              logical_bytes=logical),
+                        stats, cur)
 
-        # Delta decision (the saver owns it: only it sees the device-side
-        # dirty information).  The base is the previous entry's full
-        # object, exactly like the v1 XOR chain, and the same rebase_every
-        # bound forces periodic fulls.
-        flat = flatten_with_paths(tree)
-        base_digest, base_tbl = self._delta_base(name, kind, pref, host)
-        use_delta = base_tbl is not None
-        dirty = None
-        if use_delta:
-            dirty = [bfp.dirty_block_indices(h, b)
-                     for h, b in zip(host, base_tbl)]
-            if (sum(len(d) for d in dirty)
-                    > self.fp_max_dirty_frac * nb_total):
-                use_delta = False
+            # Delta decision (the saver owns it: only it sees the
+            # device-side dirty information).  The base is the previous
+            # entry's full object, exactly like the v1 XOR chain, and the
+            # same rebase_every bound forces periodic fulls.
+            flat = flatten_with_paths(tree)
+            base_digest, base_tbl = self._delta_base(name, kind, pref, host)
+            use_delta = base_tbl is not None
+            dirty = None
+            if use_delta:
+                dirty = [bfp.dirty_block_indices(h, b)
+                         for h, b in zip(host, base_tbl)]
+                if (sum(len(d) for d in dirty)
+                        > self.fp_max_dirty_frac * nb_total):
+                    use_delta = False
         # Enqueue all device-side gathers first, then one batched
         # device_get for the whole unit — L leaves cost one D2H round
         # trip, not L.
-        leaves = []
-        if use_delta:
-            gathered = [bfp.gather_blocks(jnp.asarray(arr), idx,
-                                          block_bytes=bb) if len(idx) else None
-                        for (_, arr), idx in zip(flat, dirty)]
-            gathered = jax.device_get(gathered)
-            for (path, _), leaf, idx, g in zip(flat, host, dirty, gathered):
-                data = b""
-                if g is not None:
-                    data = np.ascontiguousarray(g).tobytes()
+        with tracing.span("ckpt.save.d2h", **attrs):
+            if use_delta:
+                gathered = jax.device_get(
+                    [bfp.gather_blocks(jnp.asarray(arr), idx, block_bytes=bb)
+                     if len(idx) else None
+                     for (_, arr), idx in zip(flat, dirty)])
+            else:
+                host_arrs = jax.device_get([arr for _, arr in flat])
+            tracing.count("d2h_calls")
+        with tracing.span("ckpt.save.pack", **attrs):
+            leaves = []
+            if use_delta:
+                for (path, _), leaf, idx, g in zip(flat, host, dirty,
+                                                   gathered):
+                    data = b""
+                    if g is not None:
+                        data = np.ascontiguousarray(g).tobytes()
+                        stats["d2h_bytes"] += len(data)
+                        stats["blocks_moved"] += len(idx)
+                        stats["kernel_leaves"]["gather_leaves_xla"] += 1
+                    leaves.append(fputil.LeafPayload(
+                        path=path, shape=leaf.shape, dtype=leaf.dtype,
+                        nbytes=leaf.nbytes, block_bytes=bb, idx=idx,
+                        data=data))
+                packet = fputil.FingerprintPacket(
+                    digest=digest, table=tblob, leaves=leaves, full=False,
+                    base_digest=base_digest, logical_bytes=logical)
+            else:
+                for (path, _), leaf, arr in zip(flat, host, host_arrs):
+                    data = np.ascontiguousarray(arr).tobytes()
                     stats["d2h_bytes"] += len(data)
-                    stats["blocks_moved"] += len(idx)
-                    stats["kernel_leaves"]["gather_leaves_xla"] += 1
-                leaves.append(fputil.LeafPayload(
-                    path=path, shape=leaf.shape, dtype=leaf.dtype,
-                    nbytes=leaf.nbytes, block_bytes=bb, idx=idx, data=data))
-            packet = fputil.FingerprintPacket(
-                digest=digest, table=tblob, leaves=leaves, full=False,
-                base_digest=base_digest, logical_bytes=logical)
-        else:
-            host_arrs = jax.device_get([arr for _, arr in flat])
-            for (path, _), leaf, arr in zip(flat, host, host_arrs):
-                data = np.ascontiguousarray(arr).tobytes()
-                stats["d2h_bytes"] += len(data)
-                leaves.append(fputil.LeafPayload(
-                    path=path, shape=leaf.shape, dtype=leaf.dtype,
-                    nbytes=leaf.nbytes, block_bytes=bb, idx=None, data=data))
-            stats["blocks_moved"] += nb_total
-            packet = fputil.FingerprintPacket(
-                digest=digest, table=tblob, leaves=leaves, full=True,
-                base_digest=None, logical_bytes=logical)
-        # The unit's payload has fully crossed device->host; nothing has
-        # been written yet — the canonical "died after gather" drill.
-        faults.crash_point("gather")
-        if self.writer is not None:
-            return (self.writer.submit(self.store.write_fp, step, name,
-                                       kind, packet, prev_ref=pref),
+                    leaves.append(fputil.LeafPayload(
+                        path=path, shape=leaf.shape, dtype=leaf.dtype,
+                        nbytes=leaf.nbytes, block_bytes=bb, idx=None,
+                        data=data))
+                stats["blocks_moved"] += nb_total
+                packet = fputil.FingerprintPacket(
+                    digest=digest, table=tblob, leaves=leaves, full=True,
+                    base_digest=None, logical_bytes=logical)
+            # The unit's payload has fully crossed device->host; nothing
+            # has been written yet — the canonical "died after gather"
+            # drill.
+            faults.crash_point("gather")
+            if self.writer is not None:
+                return (self.writer.submit(self.store.write_fp, step, name,
+                                           kind, packet, prev_ref=pref),
+                        stats, cur)
+            return (self.store.write_fp(step, name, kind, packet,
+                                        prev_ref=pref),
                     stats, cur)
-        return (self.store.write_fp(step, name, kind, packet, prev_ref=pref),
-                stats, cur)
 
     def _delta_base(self, name: str, kind: str, pref: Optional[ChunkRef],
                     metas) -> Tuple[Optional[str], Optional[list]]:

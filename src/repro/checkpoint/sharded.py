@@ -45,6 +45,7 @@ a full-array restore whenever the shardings overlap partially.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import logging
 import shutil
@@ -57,7 +58,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.checkpoint import faults
+from repro.checkpoint import faults, tracing
 from repro.checkpoint.async_io import PendingResult
 from repro.checkpoint.backends.localfs import atomic_write
 from repro.checkpoint.chunk_store import ChunkRef
@@ -426,7 +427,6 @@ class ShardedSaver:
         the supervisor's preemption hot save (the manifest then records
         ``durable_on="hot"``; see docs/resiliency.md)."""
         mgr = self.mgr
-        t0 = time.time()
         step = int(state["step"]) if step is None else int(step)
         if prev is _LOAD_PREV:
             prev = mgr.manifests.load()
@@ -459,17 +459,17 @@ class ShardedSaver:
         new_fps: Dict[Tuple[str, str], Any] = {}
         for name in selected:
             for kind in ("weights", "opt"):
-                tree = (mgr.registry.extract_unit(state["params"], name)
-                        if kind == "weights" else
-                        mgr.registry.extract_opt_unit(state["opt"], name))
-                spec, shard_tree = self._shard_of(name, kind, tree)
+                spec, shard_tree = self._shard_of(
+                    name, kind, mgr._extract(state, name, kind))
                 if spec is None:
                     continue  # this participant owns nothing of the unit
                 specs[(name, kind)] = spec
                 pref = self._prev_shard_ref(prev, name, kind, spec)
                 ukey = self._store_key(name)
                 if not mgr.fingerprint:
-                    host = jax.device_get(shard_tree)
+                    with tracing.span("ckpt.save.d2h", unit=name, kind=kind):
+                        host = jax.device_get(shard_tree)
+                        tracing.count("d2h_calls")
                     d2h_bytes += sum(np.asarray(x).nbytes
                                      for x in jax.tree.leaves(host))
                     if mgr.writer is not None:
@@ -480,8 +480,8 @@ class ShardedSaver:
                         refs[(name, kind)] = mgr.store.write(
                             step, ukey, kind, host, prev_ref=pref)
                     continue
-                res, ustat, cur = mgr._save_unit_fp(step, ukey, kind,
-                                                    shard_tree, pref)
+                res, ustat, cur = mgr._save_unit_fp(
+                    step, ukey, kind, lambda: shard_tree, pref)
                 d2h_bytes += ustat["d2h_bytes"]
                 blocks_moved += ustat["blocks_moved"]
                 blocks_total += ustat["blocks_total"]
@@ -492,8 +492,9 @@ class ShardedSaver:
                 else:
                     refs[(name, kind)] = res
 
-        for key, p in pending.items():
-            refs[key] = p.result()
+        with tracing.span("ckpt.save.drain"):
+            for key, p in pending.items():
+                refs[key] = p.result()
         # Durability before publish: the record is the participant's
         # claim that its whole shard set survives a process loss.  The
         # preemption hot save waives it — objects on the fast tier are
@@ -536,7 +537,6 @@ class ShardedSaver:
             "blocks_moved": blocks_moved,
             "blocks_total": blocks_total,
             "kernel_leaves": kernel_leaves,
-            "seconds": time.time() - t0,
         }
         return ParticipantResult(self.participant_id, step, path, refs,
                                  stats, new_fps)
@@ -760,8 +760,49 @@ class ShardedCheckpointer:
              drift_scores: Optional[Dict[str, float]] = None,
              units: Optional[Sequence[str]] = None,
              durability_barrier: Optional[bool] = None) -> Manifest:
-        t0 = time.time()
         step = int(state["step"]) if step is None else int(step)
+        trace = tracing.Event()
+        with tracing.active(trace), tracing.span("ckpt.save"):
+            manifest, results = self._save_event(
+                state, step, meta=meta, drift_scores=drift_scores,
+                units=units, durability_barrier=durability_barrier)
+        stages, counters = trace.fold()
+        io = dict(self.mgr.store.stats)
+        d2h = sum(r.stats["d2h_bytes"] for r in results)
+        moved = sum(r.stats["blocks_moved"] for r in results)
+        total = sum(r.stats["blocks_total"] for r in results)
+        kernel_leaves = sum((r.stats["kernel_leaves"] for r in results),
+                            Counter())
+        self.mgr.last_save_stats = {
+            "step": step,
+            "selected_units": len(manifest.saved_units),
+            "total_units": len(self.mgr.registry.units),
+            "participants": self.n_participants,
+            "shard_objects": sum(r.stats["shard_objects"] for r in results),
+            "total_seconds": stages["ckpt.save"],
+            "stages": stages,
+            "d2h_bytes": d2h,
+            "d2h_calls": counters["d2h_calls"],
+            "hashed_bytes": io["hashed_bytes"],
+            "dirty_block_frac": (moved / total if total
+                                 else (0.0 if self.mgr.fingerprint else 1.0)),
+            "logical_bytes": io["logical_bytes"],
+            "written_bytes": io["written_bytes"],
+            "dedup_hits": io["dedup_hits"],
+            "delta_chunks": io["delta_chunks"],
+            "full_chunks": io["full_chunks"],
+            "backend": manifest.meta["storage"]["backend"],
+            "durable_on": manifest.meta["storage"]["durable_on"],
+            "spill_pending": manifest.meta["storage"]["pending_spill"],
+            **{k: kernel_leaves.get(k, 0) for k in KERNEL_LEAF_KEYS},
+        }
+        return manifest
+
+    def _save_event(self, state, step: int, *, meta, drift_scores, units,
+                    durability_barrier
+                    ) -> Tuple[Manifest, List[ParticipantResult]]:
+        """The body of ``save`` inside its ``ckpt.save`` span: every
+        participant's shards, then the coordinator's commit."""
         self.mgr.store.reset_stats()
         # One manifest parse for the whole event, shared by every
         # participant (they must agree on it anyway — the barrier checks
@@ -777,46 +818,23 @@ class ShardedCheckpointer:
                                      durability_barrier=barrier)
 
         if self.parallel and self.n_participants > 1:
+            # Each participant runs in a copy of this context, so its
+            # spans belong to the event.
             with ThreadPoolExecutor(
                     max_workers=self.n_participants,
                     thread_name_prefix="ckpt-shard") as pool:
-                results = list(pool.map(run, self.savers))
+                futures = [pool.submit(contextvars.copy_context().run,
+                                       run, s) for s in self.savers]
+                results = [f.result() for f in futures]
         else:
             results = [run(s) for s in self.savers]
-        manifest = self.coordinator.commit(step, self.n_participants)
-        # Commit is durable: only now may the device-side fingerprint
-        # references advance (same rule as CheckpointManager.save).
-        for r in results:
-            self.mgr._fp_refs.update(r.new_fps)
-        io = dict(self.mgr.store.stats)
-        d2h = sum(r.stats["d2h_bytes"] for r in results)
-        moved = sum(r.stats["blocks_moved"] for r in results)
-        total = sum(r.stats["blocks_total"] for r in results)
-        kernel_leaves = sum((r.stats["kernel_leaves"] for r in results),
-                            Counter())
-        self.mgr.last_save_stats = {
-            "step": step,
-            "selected_units": len(manifest.saved_units),
-            "total_units": len(self.mgr.registry.units),
-            "participants": self.n_participants,
-            "shard_objects": sum(r.stats["shard_objects"] for r in results),
-            "snapshot_bytes": d2h,
-            "total_seconds": time.time() - t0,
-            "d2h_bytes": d2h,
-            "hashed_bytes": io["hashed_bytes"],
-            "dirty_block_frac": (moved / total if total
-                                 else (0.0 if self.mgr.fingerprint else 1.0)),
-            "logical_bytes": io["logical_bytes"],
-            "written_bytes": io["written_bytes"],
-            "dedup_hits": io["dedup_hits"],
-            "delta_chunks": io["delta_chunks"],
-            "full_chunks": io["full_chunks"],
-            "backend": manifest.meta["storage"]["backend"],
-            "durable_on": manifest.meta["storage"]["durable_on"],
-            "spill_pending": manifest.meta["storage"]["pending_spill"],
-            **{k: kernel_leaves.get(k, 0) for k in KERNEL_LEAF_KEYS},
-        }
-        return manifest
+        with tracing.span("ckpt.save.commit"):
+            manifest = self.coordinator.commit(step, self.n_participants)
+            # Commit is durable: only now may the device-side fingerprint
+            # references advance (same rule as CheckpointManager.save).
+            for r in results:
+                self.mgr._fp_refs.update(r.new_fps)
+        return manifest, results
 
     def __getattr__(self, name: str):
         # restore / restore_meta / drain_spill / close / store /

@@ -233,10 +233,13 @@ def train(
     t0 = time.time()
     save_seconds = 0.0
     d2h_bytes = 0
+    d2h_calls = 0
     hashed_bytes = 0
     dirty_fracs = []
     save_timing = {"snapshot_seconds": 0.0, "stage_seconds": 0.0,
                    "writeback_seconds": 0.0, "stall_seconds": 0.0}
+    # seconds per save-path span, summed over the run's events
+    save_stages: Dict[str, float] = {}
     overlap_slices = 0
     overflow_redispatches = 0
     save_events = []
@@ -250,13 +253,16 @@ def train(
     def absorb_event(manifest):
         """Account one committed checkpoint event (either mode) from the
         manager's stats, and advance the tracker references."""
-        nonlocal save_seconds, d2h_bytes, hashed_bytes
+        nonlocal save_seconds, d2h_bytes, d2h_calls, hashed_bytes
         nonlocal overlap_slices, overflow_redispatches
         s = mgr.last_save_stats
         save_events.append(dict(s))
         for k in save_timing:
             save_timing[k] += s.get(k, 0.0)
+        for k, v in s.get("stages", {}).items():
+            save_stages[k] = save_stages.get(k, 0.0) + v
         d2h_bytes += s.get("d2h_bytes", 0)
+        d2h_calls += s.get("d2h_calls", 0)
         hashed_bytes += s.get("hashed_bytes", 0)
         dirty_fracs.append(s.get("dirty_block_frac", 1.0))
         progress.emit("ckpt", manifest.step)
@@ -407,6 +413,7 @@ def train(
         # stall is what save_seconds/ckpt_time_fraction measure in both
         # modes; snapshot/stage/writeback locate where the time went.
         **save_timing,
+        "save_stages": save_stages,
         "save_mode": "overlapped" if ov is not None else "sync",
         "ckpt_spread_steps": ckpt_spread_steps,
         "overlap_slices": overlap_slices,
@@ -414,6 +421,7 @@ def train(
         "ckpt_bytes": usage["total"],
         # fingerprint-pipeline accounting, summed over save events
         "d2h_bytes": d2h_bytes,
+        "d2h_calls": d2h_calls,
         "hashed_bytes": hashed_bytes,
         "dirty_block_frac": (float(np.mean(dirty_fracs))
                              if dirty_fracs else 0.0),
