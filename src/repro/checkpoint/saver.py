@@ -94,10 +94,9 @@ PyTree = Any
 
 
 def tables_to_host(cur: List[bfp.LeafFP]) -> List[bfp.LeafFP]:
-    """``block_fp.tree_to_host``, counted: one ``device_get`` per
-    fingerprint vector (each leaf's ``fp`` and ``sumsq``)."""
-    tracing.count("d2h_calls",
-                  sum(1 + (l.sumsq is not None) for l in cur))
+    """``block_fp.tree_to_host``, counted: one batched ``device_get`` per
+    (unit, kind)."""
+    tracing.count("d2h_calls")
     return bfp.tree_to_host(cur)
 
 

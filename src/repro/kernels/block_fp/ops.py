@@ -117,11 +117,14 @@ def _fingerprint(x, *, block_bytes, n_blocks, impl):
 def _fingerprint_many(xs, *, block_bytes, n_blocks, impl):
     """All of a unit's leaves in ONE dispatch (the save-path hot loop runs
     per unit, not per leaf — on small hosts the dispatch overhead would
-    otherwise dwarf the reduction itself)."""
+    otherwise dwarf the reduction itself).  Besides each leaf's vectors it
+    returns their fp vectors concatenated, (sum n_blocks, 2) uint32: the
+    table the host fetches in one transfer."""
     out = [_fingerprint_one(x, block_bytes=block_bytes, n_blocks=nb,
                             impl=impl)
            for x, nb in zip(xs, n_blocks)]
-    return tuple(fp for fp, _ in out), tuple(ss for _, ss in out)
+    fps = tuple(fp for fp, _ in out)
+    return fps, tuple(ss for _, ss in out), jnp.concatenate(fps)
 
 
 @jax.jit
@@ -165,7 +168,9 @@ def fingerprint_tree(tree, *, block_bytes: int = DEFAULT_BLOCK_BYTES,
     so host tables and device vectors line up index-for-index.  One jit
     dispatch per co-located device group (one per tree in the common
     case); compilations are shared across units of the same structure
-    (every stacked block reuses one executable)."""
+    (every stacked block reuses one executable).  Each leaf also carries
+    its group's concatenated table (``LeafFP.table``) for
+    ``tree_to_host``."""
     from repro.checkpoint.serial import flatten_with_paths
 
     flat = flatten_with_paths(tree)
@@ -175,17 +180,19 @@ def fingerprint_tree(tree, *, block_bytes: int = DEFAULT_BLOCK_BYTES,
         for a in arrs)
     fps: List = [None] * len(arrs)
     sss: List = [None] * len(arrs)
+    tables: List = [None] * len(arrs)
     for idxs in _device_groups(arrs):
-        f, s = _fingerprint_many(tuple(arrs[i] for i in idxs),
-                                 block_bytes=block_bytes,
-                                 n_blocks=tuple(n_blocks[i] for i in idxs),
-                                 impl=_impl(interpret))
+        f, s, t = _fingerprint_many(
+            tuple(arrs[i] for i in idxs), block_bytes=block_bytes,
+            n_blocks=tuple(n_blocks[i] for i in idxs), impl=_impl(interpret))
+        row = 0
         for i, fp, ss in zip(idxs, f, s):
-            fps[i], sss[i] = fp, ss
+            fps[i], sss[i], tables[i] = fp, ss, (t, row)
+            row += n_blocks[i]
     return [LeafFP(path=path, shape=tuple(a.shape), dtype=str(a.dtype),
                    nbytes=a.size * a.dtype.itemsize,
-                   block_bytes=block_bytes, fp=fp, sumsq=ss)
-            for (path, _), a, fp, ss in zip(flat, arrs, fps, sss)]
+                   block_bytes=block_bytes, fp=fp, sumsq=ss, table=t)
+            for (path, _), a, fp, ss, t in zip(flat, arrs, fps, sss, tables)]
 
 
 def leaves_match(cur: Sequence[LeafFP], ref: Sequence[LeafFP]) -> bool:
@@ -225,12 +232,29 @@ def gather_blocks(x: jax.Array, idx: np.ndarray, *,
 
 
 def tree_to_host(leaves: Sequence[LeafFP]) -> List[LeafFP]:
-    """Materialize device fingerprint vectors as numpy (one tiny D2H)."""
+    """Fingerprint vectors as numpy, in ONE batched ``device_get``.
+
+    A leaf that carries its device group's concatenated table
+    (``fingerprint_tree``'s output) crosses as part of that table: one
+    transfer per group, split on the host by each leaf's block count.
+    Its advisory ``sumsq`` stays the device vector (drift scoring reads
+    it there; nothing on the host does).  Any other leaf — the fused
+    gather's outputs — copies its ``fp`` and ``sumsq``, every copy
+    started before the first is waited on."""
+    tables = {id(l.table[0]): l.table[0] for l in leaves
+              if l.table is not None}
+    loose = [(l.fp, l.sumsq) for l in leaves if l.table is None]
+    host_tables, host_loose = jax.device_get((tables, loose))
+    host_loose = iter(host_loose)
     out = []
     for l in leaves:
+        if l.table is not None:
+            t, row = l.table
+            fp, ss = host_tables[id(t)][row:row + l.n_blocks], l.sumsq
+        else:
+            fp, ss = next(host_loose)
+            ss = None if ss is None else np.asarray(ss)
         out.append(LeafFP(path=l.path, shape=l.shape, dtype=l.dtype,
                           nbytes=l.nbytes, block_bytes=l.block_bytes,
-                          fp=np.asarray(jax.device_get(l.fp)),
-                          sumsq=(None if l.sumsq is None
-                                 else np.asarray(jax.device_get(l.sumsq)))))
+                          fp=np.asarray(fp), sumsq=ss))
     return out

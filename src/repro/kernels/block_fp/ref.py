@@ -35,6 +35,12 @@ class LeafFP:
     block_bytes: int
     fp: Any               # (n_blocks, 2) uint32 — hashed and compared
     sumsq: Optional[Any]  # (n_blocks,) float32 — advisory (drift scoring)
+    # Device vectors from ``fingerprint_tree`` only: the leaf's device
+    # group's fp vectors concatenated on device, (sum n_blocks, 2) uint32,
+    # and this leaf's first row in it — the group's tables cross to host
+    # as one array (``tree_to_host``).
+    table: Optional[Tuple[Any, int]] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     @property
     def n_blocks(self) -> int:

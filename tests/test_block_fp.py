@@ -1,8 +1,15 @@
 """Block fingerprint pipeline: kernel-vs-oracle property sweeps, the
 block-sparse delta v2 format, zero-D2H unchanged re-saves, restart
-recovery, and the AsyncWriter wait()/close semantics."""
+recovery, the one-transfer host tables, and the AsyncWriter
+wait()/close semantics."""
+import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +102,95 @@ def test_tree_fingerprint_roundtrip_and_match():
     assert not leaves_match(cur2, cur)
     t2 = fputil.pack_table(tree_to_host(cur2))
     assert fputil.fp_digest(t2) != fputil.fp_digest(table)
+
+
+# ------------------------------------------------- one-transfer host tables
+def _mixed_tree(devices):
+    """bf16, f32, int32 and bool leaves, a scalar, a leaf smaller than
+    one block and leaves with a partial tail block, placed on
+    ``devices`` in turn (one device group per device)."""
+    rs = np.random.RandomState(7)
+    leaves = {
+        "a_bf16": jnp.asarray(rs.standard_normal((40, 70)), jnp.bfloat16),
+        "b_f32": jnp.asarray(rs.standard_normal((3, 1024)), jnp.float32),
+        "c_i32": jnp.asarray(rs.randint(-2**31, 2**31 - 1, (100,)),
+                             jnp.int32),
+        "d_bool": jnp.asarray(rs.rand(5000) > 0.5),
+        "e_scalar": jnp.float32(3.25),
+        "f_f32": jnp.asarray(rs.standard_normal((2, 1500)), jnp.float32),
+    }
+    return {k: jax.device_put(v, devices[i % len(devices)])
+            for i, (k, v) in enumerate(sorted(leaves.items()))}
+
+
+def check_one_transfer_tables(n_devices: int, source: str) -> None:
+    """``tree_to_host`` makes one ``device_get`` and gives the same
+    tables, bit for bit, as a ``device_get`` of each leaf's vector.
+    ``source="table"`` fetches ``fingerprint_tree``'s concatenated
+    per-group tables; ``"per_leaf"`` drops them, as the fused gather's
+    outputs come, so each leaf's ``fp`` and ``sumsq`` are copied."""
+    devices = jax.devices()[:n_devices]
+    assert len(devices) == n_devices
+    cur = fingerprint_tree(_mixed_tree(devices), block_bytes=BB)
+    assert len({id(l.table[0]) for l in cur}) == n_devices
+    if source == "per_leaf":
+        cur = [dataclasses.replace(l, table=None) for l in cur]
+    calls = []
+    device_get = jax.device_get
+
+    def counted(x):
+        calls.append(x)
+        return device_get(x)
+
+    jax.device_get = counted
+    try:
+        host = tree_to_host(cur)
+    finally:
+        jax.device_get = device_get
+    assert len(calls) == 1
+
+    ref = [dataclasses.replace(l, fp=np.asarray(jax.device_get(l.fp)),
+                               sumsq=None, table=None) for l in cur]
+    assert [l.n_blocks for l in ref] == [2, 3, 1, 2, 1, 3]
+    for h, r, c in zip(host, ref, cur):
+        assert h.meta_matches(r) and h.table is None
+        assert isinstance(h.fp, np.ndarray) and h.fp.dtype == np.uint32
+        assert h.fp.shape == r.fp.shape and h.fp.tobytes() == r.fp.tobytes()
+        if source == "table":
+            assert h.sumsq is c.sumsq  # advisory: left on the device
+        else:
+            assert isinstance(h.sumsq, np.ndarray)
+            assert h.sumsq.tobytes() == np.asarray(
+                jax.device_get(c.sumsq)).tobytes()
+    assert (fputil.fp_digest(fputil.pack_table(host))
+            == fputil.fp_digest(fputil.pack_table(ref)))
+
+
+@pytest.mark.parametrize("n_devices,source", [
+    (1, "table"), (1, "per_leaf"), (2, "table")],
+    ids=["one_group", "per_leaf_vectors", "two_groups"])
+def test_one_transfer_tables_match_per_leaf_fetch(n_devices, source):
+    if n_devices <= len(jax.devices()):
+        check_one_transfer_tables(n_devices, source)
+        return
+    # jax fixes the device count at start-up: two CPU devices take a
+    # process of their own
+    tests = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                         f"{n_devices}",
+               PYTHONPATH=os.pathsep.join([str(tests.parent / "src"),
+                                           str(tests)]))
+    code = f"""
+        import test_block_fp
+        test_block_fp.check_one_transfer_tables({n_devices}, {source!r})
+        print("OK")
+    """
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env,
+                         timeout=300)
+    assert out.returncode == 0 and "OK" in out.stdout, (
+        f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}")
 
 
 # ------------------------------------------------------- block delta format

@@ -11,6 +11,7 @@ from proptest import cases, rand_shape
 from repro.checkpoint import ChunkStore
 from repro.checkpoint import compression
 from repro.checkpoint.chunk_store import content_digest
+from repro.checkpoint import saver
 from repro.checkpoint.saver import CheckpointManager
 from repro.configs import get_config
 from repro.core import (
@@ -22,6 +23,7 @@ from repro.core import (
     make_policy,
     merge,
 )
+from repro.kernels.block_fp import LeafFP
 from repro.launch import steps as steps_lib
 from repro.models import build_model
 
@@ -324,6 +326,38 @@ def test_delta_manifest_restore_equals_full_restore(tmp_path, small_setup):
     for a, b in zip(jax.tree.leaves(restored["delta"]["params"]),
                     jax.tree.leaves(state2["params"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _tables_per_leaf(cur):
+    """Reference fetch: a blocking ``device_get`` of each leaf's vectors."""
+    return [LeafFP(path=l.path, shape=l.shape, dtype=l.dtype,
+                   nbytes=l.nbytes, block_bytes=l.block_bytes,
+                   fp=np.asarray(jax.device_get(l.fp)),
+                   sumsq=np.asarray(jax.device_get(l.sumsq)))
+            for l in cur]
+
+
+def test_one_transfer_tables_store_the_same_bytes(tmp_path, small_setup,
+                                                  monkeypatch):
+    """Two events (every unit in full, then one unit as a block delta)
+    commit the same manifests and objects whether the fingerprint tables
+    cross in one transfer per unit or one fetch per leaf vector."""
+    model, state, registry = small_setup
+    state2 = _sparse_drift(registry, state, "block_001")
+    saved = {}
+    for name in ("one_transfer", "per_leaf"):
+        if name == "per_leaf":
+            monkeypatch.setattr(saver, "tables_to_host", _tables_per_leaf)
+        mgr = CheckpointManager(tmp_path / name, registry,
+                                make_policy("full", model.layer_units()),
+                                async_save=False, fp_block_bytes=4096)
+        mgr.save(state, step=10)
+        m = mgr.save(state2, step=20)
+        assert m.entries["block_001"]["weights"].stored == "delta"
+        saved[name] = ([mgr.manifests.load(s).to_json() for s in (10, 20)],
+                       set(mgr.store.iter_digests()))
+        mgr.close()
+    assert saved["one_transfer"] == saved["per_leaf"]
 
 
 def test_manager_gc_drops_only_unshared_objects(tmp_path, small_setup):

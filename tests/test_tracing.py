@@ -173,10 +173,10 @@ def test_save_timings_are_read_from_the_spans(small, tmp_path):
         assert sum(st[n] for n in SAVE_CHILDREN) <= st["ckpt.save"]
         assert sum(st.get(n, 0.0) for n in SNAPSHOT_CHILDREN) \
             <= st["ckpt.save.snapshot"]
-    # a full save fetches each leaf's two fingerprint vectors and one
-    # payload batch per (unit, kind); a clean re-save fetches nothing
+    # a full save makes one table transfer and one payload batch per
+    # (unit, kind); a clean re-save fetches nothing
     n_units = 2 * len(registry.units)
-    assert first["d2h_calls"] >= n_units
+    assert first["d2h_calls"] == 2 * n_units
     assert first["stages"]["ckpt.write.encode"] > 0
     assert again["d2h_calls"] == 0 and again["d2h_bytes"] == 0
     assert "ckpt.save.d2h" not in again["stages"]
