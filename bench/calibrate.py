@@ -11,9 +11,10 @@ Prints one JSON line per reading: ``{"seed", "role", "checks"}``, where
 below the configuration's, or the program's own lossy path) or a fault.
 
 - ``train_parity``: the program's first steps against the float32
-  reference (no window); the control is the reference with every matrix
-  product's operands rounded to float8 (e4m3); the fault ``half_batch``
-  is the reference over half of each batch's rows.
+  reference the configuration names (no window); the control is that
+  reference with every matrix product's operands rounded to float8
+  (e4m3); the fault ``half_batch`` is the reference over half of each
+  batch's rows.
 - ``resume``: the control saves the chain with the program's lossy
   ``int8`` codec and resumes once.
 - ``promote``: the program's numbers come from a short window; the

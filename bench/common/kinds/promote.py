@@ -16,7 +16,8 @@ Compared, after the window:
 - ``logit_gap``: over ``sample_batches`` served batches drawn from the
   seed, the widest gap by which a served token's logit under the plain
   float32 reference, run over the prompt and the served tokens with the
-  weights the chain holds, lies below the reference's best.
+  weights the chain holds, lies below the reference's best.  The
+  reference is the module the configuration names (``bench.ref.load``).
 """
 from __future__ import annotations
 
@@ -27,14 +28,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import ref
 from bench.common import composite, gaps, program, stategen
 from bench.common.checksum import make_checksum, mismatches, to_host
 from bench.common.kinds.resume import Driver as ResumeDriver
 from bench.common.tokens import prompts
-from bench.ref import mamba2 as ref
 
 
 class Driver(ResumeDriver):
+    def __init__(self, *, config: Dict, **kw):
+        self.ref = ref.load(config)
+        super().__init__(config=config, **kw)
+
     def setup(self):
         if self.cfg.get("ssm") is None or self.cfg.get("hybrid"):
             raise ValueError("the promote driver serves the ssm family")
@@ -133,9 +138,10 @@ class Driver(ResumeDriver):
     def _ref_logits(self, mm_dtype: Optional[str]):
         cache = self.__dict__.setdefault("_ref_fns", {})
         if mm_dtype not in cache:
-            mm, sz = ref.make_mm(mm_dtype), ref.Sizes.of(self.cfg)
+            r = self.ref
+            mm, sz = r.make_mm(mm_dtype), r.Sizes.of(self.cfg)
             cache[mm_dtype] = jax.jit(
-                lambda params, seq: ref.logits(mm, sz, params, seq))
+                lambda params, seq: r.logits(mm, sz, params, seq))
         return cache[mm_dtype]
 
     def check(self):

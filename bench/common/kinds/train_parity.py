@@ -27,6 +27,8 @@ Compared, once the window has closed and the state is freed:
   live state before the event that last saved the unit.
 
 The checkpoints go to the program's RAM tier (``program.ram_store``).
+The reference and its optimizer's weight decay are the module the
+configuration names (``bench.ref.load``).
 """
 from __future__ import annotations
 
@@ -35,6 +37,7 @@ from typing import Dict, Optional
 
 import jax
 
+from bench import ref
 from bench.common import composite, counts, gaps, program, stategen
 from bench.common.checksum import (
     leaves_with_paths,
@@ -43,7 +46,6 @@ from bench.common.checksum import (
     to_host,
 )
 from bench.common.tokens import TrainTokens
-from bench.ref import mamba2 as ref
 
 
 class Driver:
@@ -51,6 +53,7 @@ class Driver:
                  rec):
         self.cfg, self.tr, self.seed = config, traffic, seed
         self.root, self.rec = Path(root), rec
+        self.ref = ref.load(config)
         self.model = program.build(config)
         self.specs = program.state_specs(self.model)
         self.units = program.units(self.model)
@@ -201,8 +204,9 @@ class Driver:
                                           self.roots)(self.key)
         batches = [self.tokens.batch_at(s)[:rows]
                    for s in range(self.tr["checked_steps"])]
-        losses, grad, master = ref.train(mm_dtype, ref.Sizes.of(self.cfg),
-                                         master0, batches, self.opt)
+        losses, grad, master = self.ref.train(
+            mm_dtype, self.ref.Sizes.of(self.cfg), master0, batches,
+            self.opt, stacked_roots=self.roots)
         out = (losses, gaps.to_host(gaps.leaf_norms(grad)),
                gaps.to_host(gaps.diff_norms(master, master0)))
         return out

@@ -37,7 +37,7 @@ from bench.common.composite import Unit  # noqa: E402
 
 #: keys of a configuration file that describe it rather than size it
 DESCRIPTIVE = ("name", "arch", "source", "reduced", "published", "deployment",
-               "departures", "assumed")
+               "departures", "assumed", "ref")
 
 
 def build(cfg: Dict):

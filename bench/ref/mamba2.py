@@ -1,5 +1,5 @@
 """Plain reference of the Mamba2 language model (Dao and Gu, "Transformers
-are SSMs", arXiv:2405.21060), its loss, and AdamW.
+are SSMs", arXiv:2405.21060) and its loss, trained by ``bench.ref.adamw``.
 
 Written in straightforward ``jax.numpy`` from the published equations, with
 no kernel, cache, chunking or batching trick, and nothing imported from
@@ -20,7 +20,7 @@ repeated over heads; the gated RMSNorm normalises the whole inner width
 ``Precision.HIGHEST`` (the reference), or the operands rounded to a
 narrower type first (the control, ``float8_e4m3fn``).  Parameter layout:
 the stacked tree the program's checkpoints hold (``blocks/<leaf>`` with a
-leading layer axis).
+leading layer axis).  The module keeps the contract of ``bench.ref``.
 """
 from __future__ import annotations
 
@@ -28,6 +28,8 @@ from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from bench.ref import adamw
 
 F32 = jnp.float32
 
@@ -149,69 +151,9 @@ def no_decay(path, leaf_ndim: int) -> bool:
         "ln", "norm", "bias", "scale", "A_log", "D_skip", "dt_bias")))
 
 
-def lr_at(step: int, opt: Dict) -> float:
-    """Linear warm-up to the peak, then a cosine to a tenth of it."""
-    import math
-    peak, warm, total = (opt["learning_rate"], opt["warmup_steps"],
-                         opt["total_steps"])
-    if step < warm:
-        return peak * step / max(warm, 1)
-    t = min(max((step - warm) / max(total - warm, 1), 0.0), 1.0)
-    return peak * (0.1 + 0.9 * 0.5 * (1 + math.cos(math.pi * t)))
-
-
 def train(mm_dtype: Optional[str], sz: Sizes, master, batches, opt: Dict,
-          *, rows_per_block: int = 2):
-    """Plain AdamW training from ``master`` over ``batches`` (one (B, S)
-    token array per step).  Returns per-step losses, the per-leaf norms
-    of the first step's clipped gradient, and the final master tree."""
+          *, stacked_roots):
+    """Plain AdamW (``bench.ref.adamw``) of this model's summed loss."""
     mm = make_mm(mm_dtype)
-    grad_block = jax.jit(jax.value_and_grad(
-        lambda p, t: nll_sum(mm, sz, p, t)))
-    b1, b2, eps, wd = (opt["adam_b1"], opt["adam_b2"], opt["adam_eps"],
-                       opt["weight_decay"])
-    stacked = {k for k in master if k == "blocks"}
-    paths, treedef = jax.tree_util.tree_flatten_with_path(master)
-    decay = []
-    for kp, leaf in paths:
-        names = tuple(getattr(k, "key", str(k)) for k in kp)
-        ndim = leaf.ndim - (1 if names[0] in stacked else 0)
-        decay.append(not no_decay(names, ndim))
-    m = jax.tree.map(jnp.zeros_like, master)
-    v = jax.tree.map(jnp.zeros_like, master)
-    losses, first_grad = [], None
-    for step, tokens in enumerate(batches):
-        rows, seq = tokens.shape
-        total, grads = 0.0, None
-        for r in range(0, rows, rows_per_block):
-            val, g = grad_block(master, jnp.asarray(tokens[r:r + rows_per_block]))
-            total += float(val)
-            grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
-        count = rows * (seq - 1)
-        losses.append(total / count)
-        grads = jax.tree.map(lambda g: g / count, grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
-        scale = jnp.minimum(1.0, opt["grad_clip_norm"] / jnp.maximum(gnorm, 1e-12))
-        grads = jax.tree.map(lambda g: g * scale, grads)
-        if first_grad is None:
-            first_grad = grads
-        lr = lr_at(step, opt)
-        t = step + 1.0
-        c1, c2 = 1.0 - b1 ** t, 1.0 - b2 ** t
-        flat_g = treedef.flatten_up_to(grads)
-        flat_p = treedef.flatten_up_to(master)
-        flat_m = treedef.flatten_up_to(m)
-        flat_v = treedef.flatten_up_to(v)
-        new_p, new_m, new_v = [], [], []
-        for g, p, mi, vi, dec in zip(flat_g, flat_p, flat_m, flat_v, decay):
-            mi = b1 * mi + (1 - b1) * g
-            vi = b2 * vi + (1 - b2) * g * g
-            upd = (mi / c1) / (jnp.sqrt(vi / c2) + eps)
-            p = p - lr * (upd + (wd * p if dec else 0.0))
-            new_p.append(p)
-            new_m.append(mi)
-            new_v.append(vi)
-        master = treedef.unflatten(new_p)
-        m = treedef.unflatten(new_m)
-        v = treedef.unflatten(new_v)
-    return losses, first_grad, master
+    return adamw.train(lambda p, t: nll_sum(mm, sz, p, t), master, batches,
+                       opt, stacked_roots=stacked_roots, no_decay=no_decay)

@@ -20,7 +20,8 @@ if str(CHECKOUT) not in sys.path:
 
 TINY = {
     "mamba2-370m": {
-        "name": "tiny-mamba2", "arch": "mamba2-370m", "reduced": [],
+        "name": "tiny-mamba2", "arch": "mamba2-370m", "ref": "mamba2",
+        "reduced": [],
         "num_layers": 2, "d_model": 128, "vocab_size": 512,
         "tie_embeddings": True, "norm_eps": 1e-5,
         "ssm": {"state_dim": 16, "head_dim": 16, "expand": 2,

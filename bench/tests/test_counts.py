@@ -1,6 +1,7 @@
 """Operation and byte counts on shapes worked out by hand."""
 from __future__ import annotations
 
+import jax
 import pytest
 
 from bench.common import counts
@@ -13,6 +14,44 @@ def test_six_n_t():
     assert counts.active_params(leaves, tie_embeddings=True) == 800 + 128 + 8
     assert counts.active_params(leaves, tie_embeddings=False) == 128 + 8
     assert counts.train_flops(936, 1000) == 6 * 936 * 1000
+
+
+def test_reference_adamw_decays_by_each_layers_ndim():
+    """Two stacked roots: a leaf under either has one dimension fewer per
+    layer than its array, so a stacked vector is exempt from weight decay
+    and a stacked matrix is not.  With a loss of zero only the decay
+    moves a leaf."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench.ref import adamw
+
+    master = {"blocks": {"w": jnp.ones((3, 4, 4)), "b": jnp.ones((3, 4))},
+              "hybrid": {"mamba": {"w": jnp.ones((2, 4, 4)),
+                                   "b": jnp.ones((2, 4))}},
+              "head": {"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}}
+    opt = {"learning_rate": 0.1, "warmup_steps": 0, "total_steps": 10,
+           "weight_decay": 0.5, "adam_b1": 0.9, "adam_b2": 0.95,
+           "adam_eps": 1e-8, "grad_clip_norm": 1.0}
+    seen = []
+
+    def no_decay(path, ndim):
+        seen.append(("/".join(path), ndim))
+        return ndim <= 1
+
+    _, _, out = adamw.train(
+        lambda p, t: 0.0 * sum(jnp.sum(x) for x in jax.tree.leaves(p)),
+        master, [np.zeros((2, 3), np.int32)], opt,
+        stacked_roots=[("blocks",), ("hybrid", "mamba")], no_decay=no_decay)
+    assert sorted(seen) == [("blocks/b", 1), ("blocks/w", 2),
+                            ("head/b", 1), ("head/w", 2),
+                            ("hybrid/mamba/b", 1), ("hybrid/mamba/w", 2)]
+    moved = {"/".join(getattr(k, "key", "") for k in kp):
+             bool(jnp.any(leaf != 1.0))
+             for kp, leaf in jax.tree_util.tree_flatten_with_path(out)[0]}
+    assert moved == {"blocks/b": False, "blocks/w": True, "head/b": False,
+                     "head/w": True, "hybrid/mamba/b": False,
+                     "hybrid/mamba/w": True}
 
 
 @pytest.mark.parametrize("shape,dtype,want", [

@@ -47,3 +47,27 @@ def test_traced_training_run_reads_its_per_layer_metrics(tiny_files,
                                    "device_idle_share.train"}
     assert out["device"]["window_s"] > 0
     assert len(out["breakdown"]["idle_gaps"]) <= 10
+
+
+@pytest.mark.parametrize("mix", [None, "serve-promote"])
+def test_a_compared_kind_needs_the_reference_its_configuration_names(
+        mix, tiny_files):
+    """A kind that compares against a plain reference takes the one the
+    configuration names, and refuses a configuration that names none
+    (the resume kind compares against none and needs none)."""
+    files = tiny_files(CELLS[0], mix)
+    del files["config"]["ref"]
+    with pytest.raises(ValueError, match="'tiny-mamba2' names no plain"):
+        run(files)
+
+
+@pytest.mark.parametrize("name", ["../mamba2", "mamba2.py", "bench.ref", 3])
+def test_a_reference_is_named_as_a_module_of_bench_ref(name):
+    """``ref`` names a module of ``bench/ref/`` and nothing else: no path,
+    no file name, no dotted name."""
+    from bench import ref
+
+    with pytest.raises(ValueError, match="is not a module name"):
+        ref.load({"name": "tiny", "ref": name})
+    assert ref.load({"name": "tiny", "ref": "mamba2"}).__name__ == \
+        "bench.ref.mamba2"
