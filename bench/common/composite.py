@@ -2,10 +2,12 @@
 
 The rule is written here from the LLMTailor paper's parity strategy, not
 taken from the program: the first event saves every unit; after it, an
-even event saves the even-numbered blocks and every auxiliary unit except
-the embedding, an odd event saves the odd-numbered blocks and the
-embedding, and the small final norm rides with every event.  A unit
-restores to the state it had at the last event that saved it.
+even event saves the blocks at even depth and every auxiliary unit except
+the embedding, an odd event saves the blocks at odd depth and the
+embedding, and the small final norm rides with every event.  A block's
+depth is its layer's position in the model, whichever stacked array (or
+none) holds it: odd and even layers alternate.  A unit restores to the
+state it had at the last event that saved it.
 """
 from __future__ import annotations
 
@@ -20,7 +22,7 @@ class Unit(NamedTuple):
     name: str
     path: Tuple[str, ...]      # subtree of the params tree
     index: Optional[int]       # slice of a stacked subtree, or None
-    block: bool
+    depth: Optional[int]       # a block's layer in the model; None: aux
 
 
 def saves(policy: str, event: int, u: Unit) -> bool:
@@ -29,8 +31,8 @@ def saves(policy: str, event: int, u: Unit) -> bool:
     if policy != "parity":
         raise ValueError(f"no composite rule for policy {policy!r}")
     even = event % 2 == 0
-    if u.block:
-        return (u.index % 2 == 0) == even
+    if u.depth is not None:
+        return (u.depth % 2 == 0) == even
     if u.name in TINY_AUX:
         return True
     return (u.name == "embed") != even

@@ -33,7 +33,7 @@ class Driver:
         self.root, self.rec = Path(root), rec
         self.model = program.build(config)
         self.specs = program.state_specs(self.model)
-        self.units = program.units(self.model)
+        self.units = program.units(self.model, config["name"])
         self.roots = program.stacked_roots(self.model)
         self.key = stategen.seed_key(seed)
         self.store = program.ram_store()

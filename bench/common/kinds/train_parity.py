@@ -56,8 +56,13 @@ class Driver:
         self.ref = ref.load(config)
         self.model = program.build(config)
         self.specs = program.state_specs(self.model)
-        self.units = program.units(self.model)
+        self.units = program.units(self.model, config["name"])
         self.roots = program.stacked_roots(self.model)
+        self.n_active = counts.active_params(
+            [(p, s.shape)
+             for p, s in leaves_with_paths(self.specs["params"])],
+            tie_embeddings=config.get("tie_embeddings", False),
+            routed=config.get("routed"), config=config["name"])
         self.opt = traffic["optimizer"]
         self.key = stategen.seed_key(seed)
         self.tokens = TrainTokens(vocab_size=config["vocab_size"],
@@ -145,10 +150,7 @@ class Driver:
             save_stats=self.save_stats,
             train_tokens=self.window_steps * self.tr["batch"]
             * self.tr["seq_len"],
-            n_active=counts.active_params(
-                [(p, s.shape) for p, s in
-                 leaves_with_paths(self.specs["params"])],
-                tie_embeddings=self.cfg.get("tie_embeddings", False)),
+            n_active=self.n_active,
             fp_traced=self.fingerprinted(range(1, 1 + self.tr[
                 "events_per_unit"])))
         self.state = None

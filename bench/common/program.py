@@ -37,7 +37,7 @@ from bench.common.composite import Unit  # noqa: E402
 
 #: keys of a configuration file that describe it rather than size it
 DESCRIPTIVE = ("name", "arch", "source", "reduced", "published", "deployment",
-               "departures", "assumed", "ref")
+               "departures", "assumed", "ref", "routed")
 
 
 def build(cfg: Dict):
@@ -71,9 +71,24 @@ def state_specs(model):
     return steps_lib.state_specs(model)
 
 
-def units(model) -> List[Unit]:
-    return [Unit(u.name, tuple(u.path), u.index, u.kind == "block")
-            for u in model.layer_units()]
+def units(model, config: str) -> List[Unit]:
+    """The model's checkpoint units, each block with its depth: its place
+    among the blocks as the model lists them, which has to be the number
+    in its name (``block_000``, ``block_001``, ...), so that the depth
+    the composite rule reads is the model's own.  ``config``: the
+    configuration's name, for the error."""
+    out: List[Unit] = []
+    for u in model.layer_units():
+        depth = None
+        if u.kind == "block":
+            depth = sum(v.depth is not None for v in out)
+            if u.name != f"block_{depth:03d}":
+                raise ValueError(
+                    f"{config}: block unit {u.name!r} stands at depth "
+                    f"{depth}; the harness takes blocks named block_000, "
+                    f"block_001, ... in the model's order")
+        out.append(Unit(u.name, tuple(u.path), u.index, depth))
+    return out
 
 
 def stacked_roots(model):

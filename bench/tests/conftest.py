@@ -26,6 +26,22 @@ TINY = {
         "tie_embeddings": True, "norm_eps": 1e-5,
         "ssm": {"state_dim": 16, "head_dim": 16, "expand": 2,
                 "conv_kernel": 4, "chunk_size": 32, "ngroups": 1}},
+    # blocks in two places: block_000 the unstacked dense layer, block_001
+    # to block_003 rows 0-2 of the stacked MoE layers
+    "deepseek-v2-lite-16b": {
+        "name": "tiny-deepseek", "arch": "deepseek-v2-lite-16b",
+        "reduced": [],
+        "num_layers": 4, "d_model": 64, "num_heads": 4, "num_kv_heads": 4,
+        "head_dim": 24, "d_ff": 128, "vocab_size": 256,
+        "moe": {"num_experts": 4, "top_k": 2, "d_ff_expert": 32,
+                "num_shared_experts": 1, "first_k_dense": 1,
+                "d_ff_first_dense": 128, "capacity_factor": 8.0},
+        "mla": {"kv_lora_rank": 32, "q_lora_rank": 0,
+                "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+                "v_head_dim": 16},
+        "routed": {"paths": ["blocks/moe/we_gate", "blocks/moe/we_up",
+                             "blocks/moe/we_down"],
+                   "experts": 4, "per_token": 2}},
 }
 
 #: traffic sizes cut for the CPU (everything else as the cell states it)

@@ -16,6 +16,60 @@ def test_six_n_t():
     assert counts.train_flops(936, 1000) == 6 * 936 * 1000
 
 
+@pytest.fixture(scope="module")
+def deepseek_leaves():
+    from bench.common import program
+    from bench.common.checksum import leaves_with_paths
+    from bench.tests.conftest import TINY
+
+    specs = program.state_specs(program.build(TINY["deepseek-v2-lite-16b"]))
+    return [(p, s.shape) for p, s in leaves_with_paths(specs["params"])]
+
+
+#: the tiny DeepSeek's leaves by hand (untied: the embedding is left out)
+_ATTN = 32 + 64 * 32 + 64 * 8 + 2 * 32 * 4 * 16 + 4 * 16 * 64 + 64 * 4 * 24
+_NORMS = 2 * 64
+_ROUTER, _SHARED = 64 * 4, 3 * 64 * 32
+_EXPERTS = 3 * 4 * 64 * 32               # we_gate, we_up, we_down: 4 held
+_DENSE_FIRST = _ATTN + _NORMS + 3 * 64 * 128
+_OUT = 64 + 64 * 256                     # final norm, lm_head
+_MOE_DENSE = _ATTN + _NORMS + _ROUTER + _SHARED
+
+
+@pytest.mark.parametrize("routed,want", [
+    # no ``routed`` key: every held expert counts whole, as before
+    (False, _DENSE_FIRST + 3 * (_MOE_DENSE + _EXPERTS) + _OUT),
+    # 2 of 4 experts per token: the experts at half, the shared expert
+    # and the router whole
+    (True, _DENSE_FIRST + 3 * (_MOE_DENSE + _EXPERTS * 2 // 4) + _OUT),
+], ids=["no-routed", "routed"])
+def test_routed_experts_count_at_their_published_share(routed, want,
+                                                       deepseek_leaves):
+    from bench.tests.conftest import TINY
+
+    cfg = TINY["deepseek-v2-lite-16b"]
+    n = counts.active_params(
+        deepseek_leaves, tie_embeddings=False,
+        routed=cfg["routed"] if routed else None, config=cfg["name"])
+    assert n == want
+
+
+@pytest.mark.parametrize("routed,match", [
+    ({"paths": ["blocks/moe/we_gate", "blocks/moe/w_up"], "experts": 4,
+      "per_token": 2}, r"\['blocks/moe/w_up'\] match no parameter"),
+    ({"paths": ["blocks/moe/we"], "experts": 4, "per_token": 2},
+     "match no parameter"),
+    ({"paths": ["blocks/moe/we_gate"], "experts": 4, "per_token": 6},
+     "per_token <= experts"),
+    ({"paths": [], "experts": 4, "per_token": 2}, "needs paths"),
+], ids=["typo", "partial-name", "per-token-over-experts", "no-paths"])
+def test_a_routed_key_that_counts_wrong_is_refused(routed, match,
+                                                    deepseek_leaves):
+    with pytest.raises(ValueError, match="^tiny-deepseek: .*" + match):
+        counts.active_params(deepseek_leaves, tie_embeddings=False,
+                             routed=routed, config="tiny-deepseek")
+
+
 def test_reference_adamw_decays_by_each_layers_ndim():
     """Two stacked roots: a leaf under either has one dimension fewer per
     layer than its array, so a stacked vector is exempt from weight decay

@@ -123,6 +123,25 @@ def test_new_config_traffic_and_metric_by_files_alone(tmp_path):
     assert out["metrics"]["dummy_resumes"]["value"] >= 1
 
 
+def test_config_of_unstacked_and_stacked_blocks_by_files_alone(tmp_path):
+    """A DeepSeek-V2-Lite cut whose first block is unstacked and the rest
+    rows of one stack resumes its parity chain as the composite rule by
+    depth says it must (by stacked row, blocks 1-3 swapped parity and
+    block 0 had none)."""
+    root = checkout(tmp_path)
+    workload = add_cell(
+        root, TINY["deepseek-v2-lite-16b"], "resume-parity",
+        {"kind": "resume", "base_step": 1, "event_step": 2,
+         "policy": "parity", "codec": "auto"},
+        {"restore_mismatch": 0},
+        end_to_end=[{"name": "resume_s", "unit": "s", "better": "lower",
+                     "bound": 0.25, "source": "host_clock"}])
+    out = run_in(root, workload)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["restore_mismatch"]["value"] == 0
+    assert out["attempted"] >= 1 and out["failed"] == 0
+
+
 @pytest.mark.parametrize("altered", [False, True],
                          ids=["faithful", "altered"])
 @pytest.mark.parametrize("kind", ["train_parity", "promote"])
