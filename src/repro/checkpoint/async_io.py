@@ -53,6 +53,7 @@ import threading
 from multiprocessing import shared_memory
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.checkpoint import tracing
 from repro.checkpoint import workers as _workers
 from repro.checkpoint.faults import crash_point
 
@@ -169,7 +170,7 @@ class _ShmArena:
     def _size_class(n: int) -> int:
         return max(SHM_MIN_BYTES, 1 << max(1, n - 1).bit_length())
 
-    def put(self, data: bytes) -> Tuple[str, int]:
+    def put(self, data: _workers.Buffer) -> Tuple[str, int]:
         """Stage ``data`` into a segment; returns (name, length)."""
         size = self._size_class(len(data))
         with self._lock:
@@ -189,6 +190,7 @@ class _ShmArena:
                 self._segs[shm.name] = (shm, size)
                 name = shm.name
         shm.buf[:len(data)] = data
+        tracing.count("payload_copy_bytes", len(data))
         return name, len(data)
 
     def give_back(self, name: str) -> None:
@@ -510,12 +512,16 @@ class ProcessWorkerPool:
 
     def _marshal(self, obj: Any, names: List[str],
                  counted: List[int]) -> Any:
-        if isinstance(obj, (bytes, bytearray)) \
+        if isinstance(obj, (bytes, bytearray, memoryview)) \
                 and len(obj) >= self.shm_min_bytes:
-            name, length = self.arena.put(bytes(obj))
+            name, length = self.arena.put(obj)
             names.append(name)
             counted[0] += length
             return (_workers.SHM_MARK, name, length)
+        if isinstance(obj, memoryview):
+            # a small view (a view does not pickle) crosses as bytes
+            tracing.count("payload_copy_bytes", len(obj))
+            return obj.tobytes()
         if isinstance(obj, tuple):
             return tuple(self._marshal(v, names, counted) for v in obj)
         if isinstance(obj, list):
@@ -535,6 +541,7 @@ class ProcessWorkerPool:
             if len(obj) == 3 and obj[0] == _workers.SHM_MARK \
                     and isinstance(obj[1], int):
                 data = w.read_scratch(obj[1], obj[2])
+                tracing.count("payload_copy_bytes", obj[2])
                 counted[0] += obj[2]
                 return data
             return tuple(self._unstage(v, w, counted) for v in obj)

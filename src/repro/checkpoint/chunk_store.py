@@ -72,7 +72,7 @@ from typing import (TYPE_CHECKING, Any, Dict, Iterable, Iterator, Optional,
 
 import msgpack
 
-from repro.checkpoint import compression, faults, serial, tracing
+from repro.checkpoint import compression, faults, serial, tracing, workers
 from repro.checkpoint import fingerprint as fputil
 from repro.checkpoint.async_io import INLINE_DISPATCH, IoDispatch
 from repro.checkpoint.backends import StorageBackend, make_backend
@@ -556,8 +556,12 @@ class ChunkStore:
         return dict(info)
 
     def _write_object(self, digest: str, env: Dict[str, Any]) -> int:
+        """Frame ``env`` (its ``payload`` a buffer or a list of pieces) and
+        put it: the one copy of the payload on its way to the backend is
+        the join, the blob ``msgpack.packb(env)`` would give."""
         with tracing.span("ckpt.write.store"):
-            blob = msgpack.packb(env, use_bin_type=True)
+            blob = b"".join(workers.frame_object(env))
+            tracing.count("payload_copy_bytes", len(blob))
             faults.crash_point("object_write")
             self.backend.write(digest, blob)
         with self._lock:
@@ -822,9 +826,10 @@ class ChunkStore:
         # backend (same workers.py code), in a subprocess worker under the
         # process backend — identical bytes either way.
         with tracing.span("ckpt.write.encode"):
-            full_payload = canon if codec == "none" else \
-                self.dispatch.call("encode_chunk_items",
+            full_payload = [canon] if codec == "none" else \
+                self.dispatch.call("encode_chunk_pieces",
                                    serial.tree_to_items(tree), {}, codec)
+            full_nbytes = sum(len(p) for p in full_payload)
 
         # Try a delta against the previous chunk's *full* base.  Lossy
         # codecs are excluded: a delta restores the exact canonical bytes,
@@ -851,7 +856,7 @@ class ChunkStore:
                     dblob = self.dispatch.call(
                         "delta_encode", canon, base_canon,
                         "zstd" if codec == "zstd" else "none")
-                if len(dblob) < self.delta_ratio * len(full_payload):
+                if len(dblob) < self.delta_ratio * full_nbytes:
                     nbytes = self._write_object(digest, {
                         "v": OBJECT_VERSION, "format": "delta",
                         "base": base_digest, "payload": dblob})
@@ -896,16 +901,16 @@ class ChunkStore:
         try:
             table = fputil.unpack_table(packet.table)
             if packet.full:
-                # Encode straight from the packet's raw leaf bytes — no
-                # tree rebuild — via the dispatch (subprocess worker under
-                # the process backend).  Leaves arrive in flatten order,
-                # so the payload is byte-identical to
-                # ``encode_chunk(rebuild_full(leaves))``.
+                # Encode straight from the packet's leaf buffers — no
+                # tree rebuild, no copy — via the dispatch (subprocess
+                # worker under the process backend).  Leaves arrive in
+                # flatten order, so the joined payload is byte-identical
+                # to ``encode_chunk(rebuild_full(leaves))``.
                 with tracing.span("ckpt.write.encode"):
                     items = [(l.path, tuple(l.shape), l.dtype,
-                              bytes(l.data[:l.nbytes]))
+                              l.data[:l.nbytes])
                              for l in packet.leaves]
-                    payload = self.dispatch.call("encode_chunk_items",
+                    payload = self.dispatch.call("encode_chunk_pieces",
                                                  items, {}, self.codec)
                 env = {"v": OBJECT_VERSION, "format": "full",
                        "codec": self.codec, "base": None, "payload": payload,
@@ -926,11 +931,11 @@ class ChunkStore:
                      "dtype": l.dtype, "nbytes": l.nbytes,
                      "block": l.block_bytes,
                      "idx": [] if l.idx is None else list(map(int, l.idx)),
-                     # staged payloads arrive as memoryviews into a
-                     # staging slot; materialize on THIS (writer) thread
-                     "data": (l.data if isinstance(l.data, bytes)
-                              else bytes(l.data))}
+                     "data": l.data}
                     for l in packet.leaves if l.idx is None or len(l.idx)]
+                # msgpack copies each record's blocks into the body
+                tracing.count("payload_copy_bytes",
+                              sum(len(r["data"]) for r in records))
                 blob = self.dispatch.call(
                     "block_delta_encode", records,
                     "zstd" if self.codec == "zstd" else "none")

@@ -123,16 +123,24 @@ def meta_table(tree: PyTree,
 
 
 # ------------------------------------------------------------------ packets
+def byte_view(arr: np.ndarray) -> memoryview:
+    """Zero-copy flat uint8 view of an array's exact bytes.  The uint8
+    view comes first: bfloat16's buffer format ('E') is none that
+    ``memoryview`` takes."""
+    return memoryview(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
+
+
 @dataclasses.dataclass
 class LeafPayload:
     """One leaf's contribution to a write: either the full raw bytes
     (``idx is None``) or the gathered dirty blocks (padded to whole
     blocks, ``idx`` listing their positions).
 
-    ``data`` may be a zero-copy ``memoryview`` into a pinned staging
-    slot (the overlapped saver's ``async_io.StagingArena``); the chunk
-    store materializes ``bytes`` on the writer thread, and the slot is
-    only recycled after the unit's write resolves."""
+    ``data`` is a zero-copy ``memoryview``: of the host array fetched
+    from the device (the sync saver; the view keeps the array alive until
+    the writer lane has encoded it), or of a pinned staging slot (the
+    overlapped saver's ``async_io.StagingArena``, which makes it private
+    ``bytes`` on the writer thread before the slot is recycled)."""
     path: str
     shape: tuple
     dtype: str

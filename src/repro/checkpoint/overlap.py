@@ -165,13 +165,6 @@ class _Event:
     kernel_leaves: Counter = dataclasses.field(default_factory=Counter)
 
 
-def _byte_view(arr: np.ndarray) -> np.ndarray:
-    """Flat uint8 view of an array's exact bytes — extension dtypes
-    (bfloat16) don't expose a ``memoryview``-castable buffer format, a
-    uint8 view always does."""
-    return np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
-
-
 def _device_copy(arrs: Sequence[jax.Array]) -> Tuple[jax.Array, ...]:
     """Fresh device buffers for the full-save path: the originals belong
     to the train state and are donated to the next step, so the staged
@@ -483,7 +476,8 @@ class OverlappedSaver:
                     if leaf.count:
                         arr = np.asarray(leaf.dev)[:leaf.count]
                         tracing.count("d2h_calls")
-                        data = slot.pack(_byte_view(arr))
+                        data = slot.pack(fputil.byte_view(arr))
+                        tracing.count("payload_copy_bytes", data.nbytes)
                         ev.d2h_bytes += data.nbytes
                         ev.blocks_moved += leaf.count
                     payloads.append(fputil.LeafPayload(
@@ -493,7 +487,8 @@ class OverlappedSaver:
                 else:
                     arr = np.asarray(leaf.dev)
                     tracing.count("d2h_calls")
-                    data = slot.pack(_byte_view(arr))
+                    data = slot.pack(fputil.byte_view(arr))
+                    tracing.count("payload_copy_bytes", data.nbytes)
                     ev.d2h_bytes += data.nbytes
                     ev.blocks_moved += m.n_blocks
                     payloads.append(fputil.LeafPayload(
@@ -528,6 +523,7 @@ class OverlappedSaver:
             for l in packet.leaves:
                 if not isinstance(l.data, bytes):
                     l.data = bytes(l.data)
+                    tracing.count("payload_copy_bytes", len(l.data))
         except BaseException:
             # Drop every view into the slot even on failure: a live
             # memoryview pins the shm mapping and would make a later

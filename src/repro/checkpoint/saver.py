@@ -493,6 +493,8 @@ class CheckpointManager:
             # transfer/hash accounting for this event (the fingerprint win)
             "d2h_bytes": d2h_bytes,
             "d2h_calls": counters["d2h_calls"],
+            # host copies of payload bytes after the fetch (docs/perf.md)
+            "payload_copy_bytes": counters["payload_copy_bytes"],
             "hashed_bytes": io["hashed_bytes"],
             "dirty_block_frac": dirty_frac,
             # dedup/delta accounting for this event
@@ -612,8 +614,8 @@ class CheckpointManager:
                                                    gathered):
                     data = b""
                     if g is not None:
-                        data = np.ascontiguousarray(g).tobytes()
-                        stats["d2h_bytes"] += len(data)
+                        data = fputil.byte_view(g)
+                        stats["d2h_bytes"] += data.nbytes
                         stats["blocks_moved"] += len(idx)
                         stats["kernel_leaves"]["gather_leaves_xla"] += 1
                     leaves.append(fputil.LeafPayload(
@@ -625,8 +627,8 @@ class CheckpointManager:
                     base_digest=base_digest, logical_bytes=logical)
             else:
                 for (path, _), leaf, arr in zip(flat, host, host_arrs):
-                    data = np.ascontiguousarray(arr).tobytes()
-                    stats["d2h_bytes"] += len(data)
+                    data = fputil.byte_view(arr)
+                    stats["d2h_bytes"] += data.nbytes
                     leaves.append(fputil.LeafPayload(
                         path=path, shape=leaf.shape, dtype=leaf.dtype,
                         nbytes=leaf.nbytes, block_bytes=bb, idx=None,

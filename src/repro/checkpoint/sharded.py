@@ -783,6 +783,7 @@ class ShardedCheckpointer:
             "stages": stages,
             "d2h_bytes": d2h,
             "d2h_calls": counters["d2h_calls"],
+            "payload_copy_bytes": counters["payload_copy_bytes"],
             "hashed_bytes": io["hashed_bytes"],
             "dirty_block_frac": (moved / total if total
                                  else (0.0 if self.mgr.fingerprint else 1.0)),

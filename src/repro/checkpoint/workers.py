@@ -55,7 +55,7 @@ import threading
 import time
 import traceback
 import zlib
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import msgpack
 import numpy as np
@@ -86,6 +86,11 @@ BLOCK_DELTA_MAGIC = b"BD02"
 
 SHM_DIR = "/dev/shm"
 SHM_MARK = "__repro_shm__"
+
+#: What a payload may arrive as: ``bytes``, or a zero-copy flat uint8
+#: view of an array's bytes (the saver's fetched leaves), whose ``len``
+#: is its size in bytes.
+Buffer = Union[bytes, bytearray, memoryview]
 
 
 class CodecUnavailable(RuntimeError):
@@ -241,15 +246,16 @@ def decode(payload: bytes, codec: str, *, shape, dtype,
     raise ValueError(f"unknown codec {codec!r}")
 
 
-def encode_record(raw: bytes, shape, dtype: str, codec: str
-                  ) -> Tuple[bytes, str, Optional[Dict]]:
+def encode_record(raw: Buffer, shape, dtype: str, codec: str
+                  ) -> Tuple[Buffer, str, Optional[Dict]]:
     """Per-tensor encode from raw little-endian bytes (bit-identical to
-    ``encode`` on the equivalent array; the int8 path rebuilds it)."""
+    ``encode`` on the equivalent array; the int8 path rebuilds it).
+    ``raw`` may be any byte buffer; codec ``none`` returns it as it is."""
     codec = resolve_codec(codec)
     if codec == "none":
-        return bytes(raw), "none", None
+        return raw, "none", None
     if codec == "zstd":
-        return _cctx().compress(bytes(raw)), "zstd", None
+        return _cctx().compress(raw), "zstd", None
     arr = np.frombuffer(raw, dtype=np_dtype(dtype)).reshape(tuple(shape))
     return encode(arr, codec)
 
@@ -357,34 +363,89 @@ def is_block_delta(blob: bytes) -> bool:
     return blob[:4] == BLOCK_DELTA_MAGIC
 
 
+# ---------------------------------------------------------- msgpack framing
+# A stored object is msgpack.  Its bulk ``bin`` values are framed here by
+# hand, around the buffers that hold them, so the object is copied once,
+# in one join (``msgpack.Packer`` writes map and array headers, but has no
+# ``bin`` header of its own).
+
+def bin_header(n: int) -> bytes:
+    """The msgpack header of an ``n``-byte ``bin``: the smallest of bin8,
+    bin16 and bin32 that holds ``n``, as ``packb`` chooses."""
+    if n < 0x100:
+        return b"\xc4" + n.to_bytes(1, "big")
+    if n < 0x10000:
+        return b"\xc5" + n.to_bytes(2, "big")
+    if n < 0x100000000:
+        return b"\xc6" + n.to_bytes(4, "big")
+    raise ValueError(f"a msgpack bin holds under 4 GiB, not {n} bytes")
+
+
+def frame_object(env: Dict[str, Any]) -> List[Buffer]:
+    """Pieces whose join is ``msgpack.packb(env, use_bin_type=True)``,
+    with ``env["payload"]`` given as a buffer or as a list of pieces (the
+    payload is their join): every key in ``env``'s order, the payload's
+    pieces by reference."""
+    pk = msgpack.Packer(use_bin_type=True)
+    head = [pk.pack_map_header(len(env))]
+    pieces: List[Buffer] = []
+    for k, v in env.items():
+        head.append(pk.pack(k))
+        if k != "payload":
+            head.append(pk.pack(v))
+            continue
+        parts = v if isinstance(v, list) else [v]
+        head.append(bin_header(sum(len(p) for p in parts)))
+        pieces.append(b"".join(head))
+        pieces += parts
+        head = []
+    if head:
+        pieces.append(b"".join(head))
+    return pieces
+
+
 # ------------------------------------------------------ chunk payload level
 # ``items`` is the flat wire form of a tensor tree: a list of
 # (name, shape, dtype, raw_le_bytes) tuples in flatten order.  It is the
 # only tensor currency that crosses the worker pipe — never arrays, never
 # pytrees.
 
-Items = List[Tuple[str, Sequence[int], str, bytes]]
+Items = List[Tuple[str, Sequence[int], str, Buffer]]
+
+
+def encode_chunk_pieces(items: Items, meta: Dict[str, Any],
+                        codec: str) -> List[Buffer]:
+    """Chunk payload from flat items, as pieces whose join is the blob
+    ``msgpack.packb`` gives for ``{"version", "meta", "tensors"}``: the
+    framing in small ``bytes``, each record's encoded data by reference
+    (under codec ``none``, the item's own buffer), so the payload is
+    copied only where the pieces are joined."""
+    pk = msgpack.Packer(use_bin_type=True)
+    head = [pk.pack_map_header(3), pk.pack("version"),
+            pk.pack(CHUNK_FORMAT_VERSION), pk.pack("meta"), pk.pack(meta),
+            pk.pack("tensors"), pk.pack_array_header(len(items))]
+    pieces: List[Buffer] = []
+    for name, shape, dtype, raw in items:
+        data, used, extra = encode_record(raw, shape, dtype, codec)
+        fields = {"name": name, "shape": list(shape), "dtype": dtype,
+                  "codec": used, "crc": zlib.crc32(data) & 0xFFFFFFFF,
+                  "extra": extra}
+        head.append(pk.pack_map_header(len(fields) + 1))
+        for k, v in fields.items():
+            head += [pk.pack(k), pk.pack(v)]
+        head += [pk.pack("data"), bin_header(len(data))]
+        pieces += [b"".join(head), data]
+        head = []
+    if head:
+        pieces.append(b"".join(head))
+    return pieces
 
 
 def encode_chunk_items(items: Items, meta: Dict[str, Any],
                        codec: str) -> bytes:
     """Chunk payload blob from flat items (the single implementation
     behind ``serial.encode_chunk``)."""
-    tensors = []
-    for name, shape, dtype, raw in items:
-        payload, used, extra = encode_record(raw, shape, dtype, codec)
-        tensors.append({
-            "name": name,
-            "shape": list(shape),
-            "dtype": dtype,
-            "codec": used,
-            "crc": zlib.crc32(payload) & 0xFFFFFFFF,
-            "extra": extra,
-            "data": payload,
-        })
-    payload = {"version": CHUNK_FORMAT_VERSION, "meta": meta,
-               "tensors": tensors}
-    return msgpack.packb(payload, use_bin_type=True)
+    return b"".join(encode_chunk_pieces(items, meta, codec))
 
 
 def decode_chunk_items(blob: bytes, verify: bool = True
@@ -692,7 +753,7 @@ WORKER_FNS: Dict[str, Any] = {
         lambda records, compress=None: block_delta_encode(
             records, compress=compress),
     "block_delta_decode": block_delta_decode,
-    "encode_chunk_items": encode_chunk_items,
+    "encode_chunk_pieces": encode_chunk_pieces,
     "decode_chunk_items": decode_chunk_items,
     "decode_object": decode_object,
     "canonical_object": canonical_object,

@@ -234,6 +234,7 @@ def train(
     save_seconds = 0.0
     d2h_bytes = 0
     d2h_calls = 0
+    payload_copy_bytes = 0
     hashed_bytes = 0
     dirty_fracs = []
     save_timing = {"snapshot_seconds": 0.0, "stage_seconds": 0.0,
@@ -254,6 +255,7 @@ def train(
         """Account one committed checkpoint event (either mode) from the
         manager's stats, and advance the tracker references."""
         nonlocal save_seconds, d2h_bytes, d2h_calls, hashed_bytes
+        nonlocal payload_copy_bytes
         nonlocal overlap_slices, overflow_redispatches
         s = mgr.last_save_stats
         save_events.append(dict(s))
@@ -263,6 +265,7 @@ def train(
             save_stages[k] = save_stages.get(k, 0.0) + v
         d2h_bytes += s.get("d2h_bytes", 0)
         d2h_calls += s.get("d2h_calls", 0)
+        payload_copy_bytes += s.get("payload_copy_bytes", 0)
         hashed_bytes += s.get("hashed_bytes", 0)
         dirty_fracs.append(s.get("dirty_block_frac", 1.0))
         progress.emit("ckpt", manifest.step)
@@ -422,6 +425,7 @@ def train(
         # fingerprint-pipeline accounting, summed over save events
         "d2h_bytes": d2h_bytes,
         "d2h_calls": d2h_calls,
+        "payload_copy_bytes": payload_copy_bytes,
         "hashed_bytes": hashed_bytes,
         "dirty_block_frac": (float(np.mean(dirty_fracs))
                              if dirty_fracs else 0.0),

@@ -4,6 +4,7 @@ lanes, the fold into ``last_save_stats`` at the commit, and the spans in
 a profiler trace beside the device's."""
 import gc
 import glob
+import sys
 import threading
 import weakref
 
@@ -180,6 +181,48 @@ def test_save_timings_are_read_from_the_spans(small, tmp_path):
     assert first["stages"]["ckpt.write.encode"] > 0
     assert again["d2h_calls"] == 0 and again["d2h_bytes"] == 0
     assert "ckpt.save.d2h" not in again["stages"]
+
+
+def test_a_full_save_copies_its_payload_once(small, tmp_path):
+    """Sync, fingerprint path, every unit in full: after the fetch the
+    payload is copied once on the host, by the join that builds each
+    stored object, so the event copies exactly the bytes it stores."""
+    model, registry, states = small
+    mgr = _mgr(tmp_path, model, registry)
+    try:
+        mgr.save(states[0], step=10)
+        s = mgr.last_save_stats
+    finally:
+        mgr.close()
+    assert s["full_chunks"] == 2 * len(registry.units)
+    assert s["payload_copy_bytes"] == s["written_bytes"] > 0
+
+
+def test_the_sync_full_path_calls_no_tobytes_on_a_payload(small, tmp_path):
+    """The pack stage hands the writer lanes a view of each fetched leaf:
+    no ``ndarray.tobytes`` of a payload leaf runs on the train thread
+    (the fingerprint tables, ``uint32``, are packed with it)."""
+    model, registry, states = small
+    calls = []
+
+    def spy(frame, event, arg):
+        if event == "c_call" and getattr(arg, "__name__", "") == "tobytes":
+            owner = getattr(arg, "__self__", None)
+            if isinstance(owner, np.ndarray) and owner.dtype != np.uint32:
+                calls.append((owner.dtype, owner.shape))
+
+    mgr = _mgr(tmp_path, model, registry)
+    try:
+        sys.setprofile(spy)
+        try:
+            mgr.save(states[0], step=10)
+        finally:
+            sys.setprofile(None)
+        s = mgr.last_save_stats
+    finally:
+        mgr.close()
+    assert s["full_chunks"] == 2 * len(registry.units)
+    assert calls == []
 
 
 def test_overlapped_timings_are_read_from_the_spans(small, tmp_path):
